@@ -102,12 +102,14 @@ def _logit_ablation(cfg, params, page_tokens: int, prompt_len: int):
     rng = np.random.default_rng(7)
     prompt = rng.integers(0, cfg.vocab, prompt_len).astype(np.int32)
     logits = {}
+    interpret = jax.default_backend() == "cpu"
     for kvd in ("compute", "int8"):
         pool = kvcache.PagedCachePool(cfg, max_batch=1, max_seq=64,
                                       n_pages=8, page_tokens=page_tokens,
                                       kv_dtype=kvd)
         slot = pool.admit_prefill(0, prompt_len)
-        chunk = paged_step.make_paged_prefill_chunk_step(cfg, page_tokens)
+        chunk = paged_step.make_paged_prefill_chunk_step(
+            cfg, page_tokens, interpret=interpret)
         tbl = jnp.asarray(pool.page_table_row(slot), jnp.int32)
         lg, pages = chunk(params, jnp.asarray(prompt)[None], pool.pages,
                           tbl, jnp.asarray(0, jnp.int32))
@@ -115,7 +117,8 @@ def _logit_ablation(cfg, params, page_tokens: int, prompt_len: int):
         pool.lengths[slot] = prompt_len
         pool.ensure(slot, prompt_len + 1)
         tok = int(jnp.argmax(lg[0]))
-        dstep = paged_step.make_paged_decode_step(cfg, page_tokens)
+        dstep = paged_step.make_paged_decode_step(
+            cfg, page_tokens, interpret=interpret)
         lg2, _ = dstep(params, jnp.asarray([[tok]], jnp.int32), pool.pages,
                        jnp.asarray(pool.device_page_tables()),
                        jnp.asarray([prompt_len], jnp.int32),

@@ -28,15 +28,14 @@ Asserts:
 Usage:  PYTHONPATH=src python benchmarks/bench_overlap.py [--smoke]
 
 Re-execs itself with ``XLA_FLAGS=--xla_force_host_platform_device_count=4``
-when the process initialised jax with fewer than 2 devices (same contract
-as bench_trace). Appends the ``overlap`` section to BENCH_serve.json and
-writes benchmarks/results/overlap.json.
+when the process initialised jax with fewer than 2 devices, and fails on a
+non-CPU backend (same contract as bench_trace). Appends the ``overlap``
+section to BENCH_serve.json and writes benchmarks/results/overlap.json.
 """
 from __future__ import annotations
 
 import argparse
 import os
-import subprocess
 import sys
 import time
 
@@ -51,7 +50,7 @@ if "jax" not in sys.modules and _FORCE.split("=")[0] not in \
 import jax
 import numpy as np
 
-from benchmarks.common import save_bench, save_json
+from benchmarks.common import rerun_on_host_devices, save_bench, save_json
 
 TP = 2
 NONCOMPUTE = ("schedule", "fetch", "dma")   # what the host still serializes
@@ -110,27 +109,10 @@ def _side(eng, done, wall, summary, tstats):
     return out
 
 
-def _reexec(smoke: bool, arch: str) -> None:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + _FORCE).strip()
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    cmd = [sys.executable, os.path.abspath(__file__), "--arch", arch]
-    if smoke:
-        cmd.append("--smoke")
-    res = subprocess.run(cmd, env=env, text=True, capture_output=True)
-    sys.stdout.write(res.stdout)
-    sys.stderr.write(res.stderr)
-    if res.returncode:
-        raise RuntimeError("bench_overlap subprocess failed")
-
-
 def run(smoke: bool = True, arch: str = "qwen2-0.5b", n_slots: int = 2,
         max_seq: int = 64, page_tokens: int = 8, hot_pages: int = 4,
         token_budget: int = 10):
-    if len(jax.devices()) < TP:
-        _reexec(smoke, arch)
+    if rerun_on_host_devices(__file__, TP, smoke, arch):
         return None
     from repro import configs
     from repro.models import blocks, transformer

@@ -25,7 +25,9 @@ Usage:  PYTHONPATH=src python benchmarks/bench_tensor_parallel.py [--smoke]
 
 When the current process already initialised jax with fewer than 4 devices
 (e.g. under benchmarks/run.py), the bench re-execs itself in a subprocess
-with ``XLA_FLAGS=--xla_force_host_platform_device_count=4``. Appends the
+with ``XLA_FLAGS=--xla_force_host_platform_device_count=4``. It runs on the
+CPU backend only: elsewhere it fails, since this process then holds the
+accelerator and the subprocess could never reach it. Appends the
 ``tensor_parallel`` section to BENCH_serve.json and writes
 benchmarks/results/tensor_parallel.json.
 """
@@ -33,7 +35,6 @@ from __future__ import annotations
 
 import argparse
 import os
-import subprocess
 import sys
 import time
 
@@ -48,7 +49,7 @@ if "jax" not in sys.modules and _FORCE.split("=")[0] not in \
 import jax
 import numpy as np
 
-from benchmarks.common import save_bench, save_json
+from benchmarks.common import rerun_on_host_devices, save_bench, save_json
 
 TP_LEVELS = (1, 2, 4)
 
@@ -80,28 +81,9 @@ def _drive(eng, schedule, max_iters=5000):
             raise RuntimeError("tp bench workload did not drain")
 
 
-def _reexec(smoke: bool, arch: str) -> None:
-    """Re-run this bench in a subprocess with 4 forced host devices (the
-    current process initialised jax before the flag could apply)."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + _FORCE).strip()
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    cmd = [sys.executable, os.path.abspath(__file__), "--arch", arch]
-    if smoke:
-        cmd.append("--smoke")
-    res = subprocess.run(cmd, env=env, text=True, capture_output=True)
-    sys.stdout.write(res.stdout)
-    sys.stderr.write(res.stderr)
-    if res.returncode:
-        raise RuntimeError("bench_tensor_parallel subprocess failed")
-
-
 def run(smoke: bool = True, arch: str = "qwen2-0.5b", token_budget: int = 14,
         page_tokens: int = 8, n_slots: int = 4):
-    if len(jax.devices()) < max(TP_LEVELS):
-        _reexec(smoke, arch)
+    if rerun_on_host_devices(__file__, max(TP_LEVELS), smoke, arch):
         return None
     from repro import configs
     from repro.models import blocks, transformer

@@ -25,7 +25,9 @@ Usage:  PYTHONPATH=src python benchmarks/bench_trace.py [--smoke]
 
 When the current process already initialised jax with fewer than 2 devices
 (e.g. under benchmarks/run.py), the bench re-execs itself in a subprocess
-with ``XLA_FLAGS=--xla_force_host_platform_device_count=4``. Appends the
+with ``XLA_FLAGS=--xla_force_host_platform_device_count=4``. It runs on the
+CPU backend only: elsewhere it fails, since this process then holds the
+accelerator and the subprocess could never reach it. Appends the
 ``trace`` section to BENCH_serve.json and writes
 benchmarks/results/trace.json.
 """
@@ -34,7 +36,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -49,7 +50,8 @@ if "jax" not in sys.modules and _FORCE.split("=")[0] not in \
 import jax
 import numpy as np
 
-from benchmarks.common import REPO_ROOT, save_bench, save_json
+from benchmarks.common import (REPO_ROOT, rerun_on_host_devices,
+                               save_bench, save_json)
 
 TP = 2
 CLOSURE_TOL_PCT = 5.0       # per-iteration |sum(buckets) - dur| / dur bound
@@ -114,29 +116,10 @@ def _closure_worst_err_pct(stall_log) -> float:
     return worst
 
 
-def _reexec(smoke: bool, arch: str) -> None:
-    """Re-run this bench in a subprocess with 4 forced host devices (the
-    current process initialised jax before the flag could apply)."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + _FORCE).strip()
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    cmd = [sys.executable, os.path.abspath(__file__), "--arch", arch]
-    if smoke:
-        cmd.append("--smoke")
-    res = subprocess.run(cmd, env=env, text=True, capture_output=True)
-    sys.stdout.write(res.stdout)
-    sys.stderr.write(res.stderr)
-    if res.returncode:
-        raise RuntimeError("bench_trace subprocess failed")
-
-
 def run(smoke: bool = True, arch: str = "qwen2-0.5b", n_slots: int = 2,
         max_seq: int = 64, page_tokens: int = 8, hot_pages: int = 4,
         token_budget: int = 10):
-    if len(jax.devices()) < TP:
-        _reexec(smoke, arch)
+    if rerun_on_host_devices(__file__, TP, smoke, arch):
         return None
     from repro import configs
     from repro.models import blocks, transformer

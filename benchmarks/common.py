@@ -4,12 +4,52 @@ from __future__ import annotations
 
 import json
 import os
+import subprocess
+import sys
 import time
 from typing import Dict
 
 from repro.core import perf
 
 RESULTS = os.path.join(os.path.dirname(__file__), "results")
+
+_FORCE_HOST_DEVICES = "--xla_force_host_platform_device_count=4"
+
+
+def rerun_on_host_devices(script: str, n_devices: int, smoke: bool,
+                          arch: str) -> bool:
+    """Give a bench of a multi-device path its forced CPU host devices.
+
+    Returns False when this process already sees ``n_devices`` CPU devices
+    (the caller runs the bench here). Otherwise JAX was initialised before
+    the bench could force host devices: re-run ``script`` in a child with
+    four of them and return True. A non-CPU backend is refused, because this
+    process then holds the accelerator and a child could never reach it.
+    """
+    import jax
+    name = os.path.basename(script)
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"{name} measures a multi-device path on forced CPU host devices "
+            f"and cannot run on the {backend} backend; run it with "
+            "JAX_PLATFORMS=cpu")
+    if len(jax.devices()) >= n_devices:
+        return False
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " "
+                        + _FORCE_HOST_DEVICES).strip()
+    src = os.path.join(REPO_ROOT, "src")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    cmd = [sys.executable, os.path.abspath(script), "--arch", arch]
+    if smoke:
+        cmd.append("--smoke")
+    res = subprocess.run(cmd, env=env, text=True, capture_output=True)
+    sys.stdout.write(res.stdout)
+    sys.stderr.write(res.stderr)
+    if res.returncode:
+        raise RuntimeError(f"{name} subprocess failed")
+    return True
 
 # --- the paper's hardware (HEROv2 'Aurora': 8×CV32E40P @ 50 MHz, DDR4) -----
 # cycle model calibrated against the paper's own Fig. 4/5 measurements

@@ -26,11 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # TPU backend primitives — present in jax but only lower on TPU
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAVE_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 
 # --------------------------------------------------------------------------
@@ -133,7 +129,7 @@ def copy_async(src_ref, dst_ref, sem=None):
     identical, so kernel code is portable (the paper's 'unified over all
     accelerators with per-accelerator optimized implementation').
     """
-    if _HAVE_PLTPU and sem is not None:
+    if sem is not None:
         cp = pltpu.make_async_copy(src_ref, dst_ref, sem)
         cp.start()
         return cp

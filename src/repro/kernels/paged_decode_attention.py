@@ -43,8 +43,8 @@ NEG = -1e30
 
 def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                        page_table: jax.Array, lengths: jax.Array,
-                       k_scale=None, v_scale=None,
-                       interpret: bool = True) -> jax.Array:
+                       k_scale=None, v_scale=None, *,
+                       interpret: bool) -> jax.Array:
     """One-token attention over a paged KV cache.
 
     q:          [B, H, hd]
@@ -58,8 +58,10 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                 softmax accumulation. The scale block rides the same
                 prefetched page-table walk as its page (its BlockSpec
                 index_map is the table lookup), so quantization adds one
-                scalar-sized block per page, no extra gather.
+                K-wide scale row per page, no extra gather.
     v_scale:    optional [P, K] f32 (must accompany ``k_scale``)
+    interpret:  run the Pallas body in the interpreter (CPU) instead of
+                compiling it with Mosaic (TPU).
     Returns [B, H, hd].
     """
     B, H, hd = q.shape
@@ -102,8 +104,8 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             if quant:
                 # dequantize in VMEM: int8 page block × per-(page, head)
                 # scale → f32, feeding the same f32 accumulation below
-                kb = kb * ks_ref[0, 0]
-                vb = vb * vs_ref[0, 0]
+                kb = kb * head_scale(ks_ref, bk % K, pt)
+                vb = vb * head_scale(vs_ref, bk % K, pt)
             s = jnp.dot(qb, kb.T, preferred_element_type=jnp.float32) * scale
             kpos = j * pt + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(kpos < seq_len, s, NEG)
@@ -133,14 +135,11 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     ]
     inputs = [table, lengths_bk, qr, k_pages, v_pages]
     if quant:
-        # scale blocks walk the same prefetched table as their pages
-        in_specs += [
-            pl.BlockSpec((1, 1),
-                         lambda bk, j, tbl, lens: (tbl[bk // K, j], bk % K)),
-            pl.BlockSpec((1, 1),
-                         lambda bk, j, tbl, lens: (tbl[bk // K, j], bk % K)),
-        ]
-        inputs += [k_scale, v_scale]
+        # scale rows walk the same prefetched table as their pages, in a
+        # [P, 1, K] view (see head_scale)
+        in_specs += [pl.BlockSpec((1, 1, K), lambda bk, j, tbl, lens:
+                                  (tbl[bk // K, j], 0, 0))] * 2
+        inputs += [k_scale.reshape(P, 1, K), v_scale.reshape(P, 1, K)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # page_table, lengths_bk
@@ -159,6 +158,22 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         interpret=interpret,
     )(*inputs)
     return out.reshape(B, H, hd)
+
+
+def head_scale(scale_ref, kv_head, rows: int) -> jax.Array:
+    """Pick ``kv_head``'s scale out of a page's ``(1, 1, K)`` scale block as
+    a ``(rows, 1)`` column, ready to scale a ``(rows, hd)`` page block.
+
+    The kernels see the ``[P, K]`` scales as ``[P, 1, K]``: Mosaic tiles the
+    last two block dims by (8, 128) unless they span the whole array, so a
+    ``(1, 1)`` block over ``[P, K]`` cannot lower, while ``(1, 1, K)`` spans
+    both. The pick is a masked lane sum, since Mosaic has no dynamic lane
+    load; the row is broadcast over sublanes first, since Mosaic cannot
+    broadcast a ``(1, 1)`` value over sublanes and lanes at once."""
+    row = jnp.broadcast_to(scale_ref[0], (rows, scale_ref.shape[-1]))
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.sum(jnp.where(lane == kv_head, row, 0.0), axis=1,
+                   keepdims=True)
 
 
 def gather_pages(pages: jax.Array, page_table: jax.Array) -> jax.Array:

@@ -38,15 +38,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.paged_decode_attention import gather_pages
+from repro.kernels.paged_decode_attention import gather_pages, head_scale
 
 NEG = -1e30
 
 
 def paged_flash_prefill(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                         page_table: jax.Array, start: jax.Array,
-                        k_scale=None, v_scale=None,
-                        interpret: bool = True) -> jax.Array:
+                        k_scale=None, v_scale=None, *,
+                        interpret: bool) -> jax.Array:
     """Chunk attention over a paged KV cache with cross-chunk causal masking.
 
     q:          [C, H, hd] — chunk queries at global positions
@@ -61,6 +61,8 @@ def paged_flash_prefill(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                 VMEM (int8 rows × scale → f32) before the f32 accumulation;
                 the scale BlockSpec walks the same prefetched page table.
     v_scale:    optional [P, K] f32 (must accompany ``k_scale``)
+    interpret:  run the Pallas body in the interpreter (CPU) instead of
+                compiling it with Mosaic (TPU).
     Returns [C, H, hd].
     """
     C, H, hd = q.shape
@@ -83,6 +85,7 @@ def paged_flash_prefill(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
         else:
             o_ref, m_ref, l_ref, acc_ref = rest
+        kk = pl.program_id(0)
         j = pl.program_id(1)
 
         @pl.when(j == 0)
@@ -101,8 +104,8 @@ def paged_flash_prefill(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             if quant:
                 # dequantize in VMEM: int8 page block × per-(page, head)
                 # scale → f32, feeding the same f32 accumulation below
-                kb = kb * ks_ref[0, 0]
-                vb = vb * vs_ref[0, 0]
+                kb = kb * head_scale(ks_ref, kk, pt)
+                vb = vb * head_scale(vs_ref, kk, pt)
             s = jnp.dot(qb, kb.T, preferred_element_type=jnp.float32) * scale
             # cross-chunk causal frontier: row r is query c = r // G at
             # global position start + c; key col is global position j·pt + col
@@ -135,12 +138,11 @@ def paged_flash_prefill(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     ]
     inputs = [table, meta, qr, k_pages, v_pages]
     if quant:
-        # scale blocks walk the same prefetched table as their pages
-        in_specs += [
-            pl.BlockSpec((1, 1), lambda kk, j, tbl, meta: (tbl[j], kk)),
-            pl.BlockSpec((1, 1), lambda kk, j, tbl, meta: (tbl[j], kk)),
-        ]
-        inputs += [k_scale, v_scale]
+        # scale rows walk the same prefetched table as their pages, in a
+        # [P, 1, K] view (see head_scale)
+        in_specs += [pl.BlockSpec((1, 1, K), lambda kk, j, tbl, meta:
+                                  (tbl[j], 0, 0))] * 2
+        inputs += [k_scale.reshape(P, 1, K), v_scale.reshape(P, 1, K)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # page_table, meta (start)

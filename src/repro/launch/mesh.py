@@ -6,19 +6,19 @@ device state (the dry-run must set XLA_FLAGS before first jax init).
 """
 from __future__ import annotations
 
-import jax
+from repro.parallel.sharding import auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_dev_mesh(n_devices: int = 1, model: int = 1):
     """Small mesh for tests on local devices."""
     data = n_devices // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 def chips(mesh) -> int:

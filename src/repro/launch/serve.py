@@ -1,12 +1,16 @@
 """Serving driver: mailbox-batched continuous decoding.
 
-Usage (CPU container, reduced config):
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --requests 8
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --paged \
-      --page-tokens 16 --pages 32
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --tiered \
-      --pages 8 --host-budget-mb 64 --requests 16
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
+The model is served at its published widths (``configs.get_config``);
+``--smoke`` swaps in the reduced ``smoke_config()`` of the same family,
+which is what a CPU run with interpret-mode kernels can afford. Usage on
+a CPU host:
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --smoke \
+      --requests 8
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --smoke \
+      --paged --page-tokens 16 --pages 32
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --smoke \
+      --tiered --pages 8 --host-budget-mb 64 --requests 16
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --smoke \
       --chunked-prefill --token-budget 24 --requests 16
 
 ``--paged`` switches the engine to the page-table KV cache (vmm-backed pool +
@@ -32,17 +36,19 @@ adopt the ref-counted pages of their longest cached prefix instead of
 re-prefilling it; ``--prefix-cache-pages`` caps how many hot pages the cache
 may pin (LRU-evicted on demand).
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --smoke \
       --prefix-cache --shared-prefix-len 32 --requests 12
 
 ``--tp N`` shards the paged serving path over N devices (tensor parallelism:
 KV pages and the paged-attention head walk shard along the kv-head axis;
 page tables and the allocator stay host-side and replicated — see
-serve/executor.py). Greedy streams are bit-identical to --tp 1. On a CPU
-container, force host devices first:
+serve/executor.py). On the CPU greedy streams are bit-identical to --tp 1;
+on TPU v5e chips they differed (cause not yet traced), and both stay
+within the bf16 tolerance of a float32 reference (``chip_smoke.py --tp
+2``). On a CPU container, force host devices first:
 
   XLA_FLAGS=--xla_force_host_platform_device_count=4 \
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --smoke \
       --chunked-prefill --tp 2 --requests 8
 
 Observability + SLO policy (PR 6): ``--metrics-log N`` prints one
@@ -55,7 +61,7 @@ requests through that many priority classes (highest class first out of
 the mailbox; see serve/policy.py). Shed requests are reported with their
 typed verdicts at the end of the run.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --smoke \
       --tiered --pages 8 --requests 16 --max-in-system 4 --max-queue 4 \
       --priorities 2 --metrics-log 16
 
@@ -68,7 +74,7 @@ prints a ``[serve:trace]`` stall-attribution line decomposing iteration
 wall time into schedule/fetch/dma/other. ``--trace-buffer N`` bounds the
 in-memory event ring (oldest events drop first).
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --smoke \
       --tiered --pages 8 --requests 16 --trace /tmp/serve.trace.json
 
 Fleet serving (PR 9): ``--replicas N`` routes the request mix through a
@@ -79,7 +85,7 @@ backpressure when every replica's SLO gate refuses, per-replica namespaced
 metrics. Greedy streams are bit-identical to a single engine regardless of
 replica count or router.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --smoke \
       --prefix-cache --shared-prefix-len 32 --requests 12 --replicas 2
 """
 from __future__ import annotations
@@ -92,6 +98,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import blocks, transformer
 from repro.serve.cache import CacheConfig
 from repro.serve.engine import Engine, EngineConfig, Request
@@ -148,9 +155,12 @@ def _serve_fleet(cfg, params, econf, args):
               f"{s.get('prefix_shared_tokens', 0)}")
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced smoke_config() instead of the "
+                         "published widths (CPU runs)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=128)
@@ -202,7 +212,7 @@ def main():
     ap.add_argument("--tp", type=int, default=1,
                     help="tensor-parallel degree: shard KV pages + paged "
                          "attention over this many devices (kv-head axis; "
-                         "implies --paged; streams bit-identical to --tp 1)")
+                         "implies --paged)")
     ap.add_argument("--metrics-log", type=int, default=0, metavar="N",
                     help="print a [metrics] JSON snapshot line every N "
                          "engine iterations (0 = off)")
@@ -234,11 +244,23 @@ def main():
                     help="fleet placement policy: longest prefix-"
                          "fingerprint match (occupancy tie-break) or plain "
                          "round-robin (--replicas > 1 only)")
-    args = ap.parse_args()
+    return ap
 
-    cfg = configs.get_smoke_config(args.arch)
-    params_t = transformer.init_model(jax.random.PRNGKey(0), cfg)
+
+def load_model(args, seed: int = 0):
+    """The model ``args`` (from :func:`build_parser`) names, with random
+    weights from ``seed``: published widths, or ``smoke_config()`` under
+    ``--smoke``. Returns (cfg, params)."""
+    cfg = (configs.get_smoke_config(args.arch) if args.smoke
+           else configs.get_config(args.arch))
+    params_t = transformer.init_model(jax.random.PRNGKey(seed), cfg)
     params, _ = blocks.split_params(params_t)
+    return cfg, params
+
+
+def engine_config(args) -> EngineConfig:
+    """The engine configuration ``args`` describe: what the CLI serves, and
+    what ``chip_smoke.py`` builds its engines from."""
     policy = None
     if (args.max_in_system is not None or args.max_queue is not None
             or args.itl_target_ms is not None):
@@ -251,7 +273,7 @@ def main():
     trace_kw = {}
     if args.trace_buffer is not None:
         trace_kw["trace_buffer"] = args.trace_buffer
-    econf = EngineConfig(
+    return EngineConfig(
         n_slots=args.slots, max_seq=args.max_seq,
         chunked=args.chunked_prefill, token_budget=args.token_budget,
         preempt_quantum=args.preempt_quantum, overlap=not args.no_overlap,
@@ -266,6 +288,13 @@ def main():
             prefix=args.prefix_cache,
             prefix_pages=args.prefix_cache_pages,
             kv_dtype=args.kv_dtype))
+
+
+def main():
+    args = build_parser().parse_args()
+    enable_compile_cache()
+    cfg, params = load_model(args)
+    econf = engine_config(args)
     if args.replicas > 1:
         _serve_fleet(cfg, params, econf, args)
         return
@@ -334,7 +363,7 @@ def main():
               f"{s['prefill_chunks']} ({s['prefill_chunk_tokens']} tok), "
               f"decode tokens {s['decode_tokens']}, ttft p50/p99 "
               f"{s['ttft_p50_s']:.3f}/{s['ttft_p99_s']:.3f} s")
-    if policy is not None:
+    if econf.policy is not None:
         s = eng.stats_summary()
         by_code = {}
         for r in eng.shed:

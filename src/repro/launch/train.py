@@ -13,10 +13,7 @@ this container:
     simulates a crash at step N to exercise the path (tests/test_driver.py);
   * elastic scaling: on restart the mesh is rebuilt from the CURRENTLY
     visible devices and parameters are re-device_put under the new sharding
-    (checkpoint stores host arrays — mesh-shape-agnostic);
-  * XLA latency-hiding flags for collective/compute overlap are set when the
-    backend is TPU (--xla_enable_async_collectives etc.) — documented here,
-    inert on CPU.
+    (checkpoint stores host arrays — mesh-shape-agnostic).
 
 Usage (CPU container, reduced config):
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b --smoke \
@@ -37,15 +34,12 @@ from repro import configs
 from repro.checkpoint.manager import CheckpointManager
 from repro.core.offload import TargetRegion
 from repro.data import pipeline as dp
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import blocks, transformer
 from repro.optim import adamw
 from repro.parallel import compression
 from repro.parallel import sharding as shlib
 from repro.train import step as steps
-
-TPU_FLAGS = ("--xla_tpu_enable_async_collective_fusion=true "
-             "--xla_tpu_enable_latency_hiding_scheduler=true "
-             "--xla_tpu_overlap_compute_collective_tc=true")
 
 
 class SimulatedFailure(RuntimeError):
@@ -72,7 +66,7 @@ def train(arch: str, smoke: bool, steps_total: int, ckpt_dir: str,
           log_every: int = 10):
     cfg = (configs.get_smoke_config(arch) if smoke else configs.get_config(arch))
     n_dev = jax.device_count()
-    mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+    mesh = shlib.auto_mesh((n_dev, 1), ("data", "model"))
     mgr = CheckpointManager(ckpt_dir)
     dcfg = dp.DataConfig(vocab=cfg.vocab, seq_len=seq, global_batch=batch,
                          seed=seed, mtp=cfg.mtp)
@@ -153,6 +147,7 @@ def main():
     ap.add_argument("--compress", default="none",
                     choices=["none", "bf16", "int8"])
     args = ap.parse_args()
+    enable_compile_cache()
     train(args.arch, args.smoke, args.steps, args.ckpt_dir, args.batch,
           args.seq, args.lr, ckpt_every=args.ckpt_every,
           inject_failure=args.inject_failure, grad_accum=args.grad_accum,

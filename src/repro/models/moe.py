@@ -172,14 +172,6 @@ def moe_forward(p: Dict[str, jax.Array], x: jax.Array, cfg: MoeConfig
     capacity slots (40∤16 granite); combine = psum over 'model'."""
     from repro.parallel import sharding as shlib
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map as _sm
-        shard_map = lambda f, mesh, in_specs, out_specs: _sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as _sme
-        shard_map = lambda f, mesh, in_specs, out_specs: _sme(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
     B, L, d = x.shape
     mesh = shlib.current_mesh()
@@ -260,10 +252,10 @@ def moe_forward(p: Dict[str, jax.Array], x: jax.Array, cfg: MoeConfig
         else:
             xspec = P(batch_axes if batch_axes else None, None, None)
             fn = local
-        y, aux = shard_map(
-            fn, mesh,
-            (xspec, P(None, None), wspec, wspec, wspec),
-            (xspec, P()),
+        y, aux = jax.shard_map(
+            fn, mesh=mesh,
+            in_specs=(xspec, P(None, None), wspec, wspec, wspec),
+            out_specs=(xspec, P()),
         )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
         y = y.reshape(B * L, d)
         aux = aux if aux.ndim == 0 else aux[()]

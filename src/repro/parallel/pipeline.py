@@ -23,7 +23,6 @@ from typing import Any, Callable, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def gpipe(layer_fn: Callable[[Any, jax.Array], jax.Array],
@@ -53,10 +52,7 @@ def gpipe(layer_fn: Callable[[Any, jax.Array], jax.Array],
         def _varying(a):
             # scan carries become stage-varying after ppermute; the initial
             # value must carry the same vma type
-            try:
-                return jax.lax.pcast(a, (stage_axis,), to="varying")
-            except (AttributeError, TypeError):
-                return a
+            return jax.lax.pcast(a, (stage_axis,), to="varying")
 
         buf = _varying(jnp.zeros_like(xs[0]))
 
@@ -87,9 +83,9 @@ def gpipe(layer_fn: Callable[[Any, jax.Array], jax.Array],
     pspec_params = P(stage_axis)   # leading layer axis sharded into stages
     pspec_x = P()                  # microbatches replicated across stages
 
-    return shard_map(stage_fwd, mesh=mesh,
-                     in_specs=(pspec_params, pspec_x),
-                     out_specs=pspec_x)
+    return jax.shard_map(stage_fwd, mesh=mesh,
+                         in_specs=(pspec_params, pspec_x),
+                         out_specs=pspec_x)
 
 
 def bubble_fraction(n_stages: int, n_microbatches: int) -> float:

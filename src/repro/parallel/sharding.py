@@ -20,7 +20,7 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # logical axis -> preferred mesh axes (in order; filtered by mesh presence)
 DEFAULT_RULES: Tuple[Tuple[str, Optional[Tuple[str, ...]]], ...] = (
@@ -64,6 +64,13 @@ def use_mesh(mesh: Mesh, overrides: Optional[Dict[str, Optional[Tuple[str, ...]]
             yield mesh
     finally:
         _CTX.mesh, _CTX.rules = prev_mesh, prev_rules
+
+
+def auto_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: the logical-rule layer
+    places arrays through ``with_sharding_constraint``, which rejects the
+    ``Explicit`` axes ``jax.make_mesh`` gives by default."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def current_mesh() -> Optional[Mesh]:

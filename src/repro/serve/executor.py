@@ -21,8 +21,9 @@ decisions and the device↔host data discipline around them:
     weights, and the host-side allocator stay replicated. Inside the shard,
     paged_decode_attention / paged_prefill_attention run on their head slice
     and a single all-gather of per-head partial outputs rebuilds the full
-    head dimension (a concatenation, never a reduction — so tp=N greedy
-    streams are bit-identical to tp=1).
+    head dimension (a concatenation, never a reduction — so on the CPU
+    tp=N greedy streams are bit-identical to tp=1; on TPU chips the streams
+    have differed within bf16 tolerance, cause not yet traced).
 
 Ownership boundaries & invariants:
 
@@ -51,12 +52,6 @@ from repro.models import transformer
 from repro.parallel import sharding
 from repro.serve import paged_step, trace
 from repro.train import step as steps
-
-try:                                    # jax >= 0.5 moved it to the top level
-    from jax import shard_map as _shard_map_mod  # type: ignore
-    shard_map = _shard_map_mod
-except ImportError:
-    from jax.experimental.shard_map import shard_map
 
 # KV-page pool leaves are [count, P, K, pt, hd]: shard the kv-head axis
 _PAGES_SPEC = P(None, None, sharding.TP_AXIS)
@@ -88,18 +83,21 @@ class Executor:
     dispatches one TargetRegion asynchronously and returns device-resident
     sampled ids plus the updated cache arrays; ``fetch_token_ids`` batches
     the iteration's ids into one host transfer.
+
+    Pallas kernels run in interpret mode only when the executor's devices
+    are CPUs; on an accelerator they are compiled, and a kernel that does
+    not compile fails the step instead of falling back.
     """
 
     def __init__(self, cfg: transformer.ModelConfig, params, *,
                  paged: bool, chunked: bool = False, page_tokens: int = 16,
-                 tp: int = 1, interpret: bool = True):
+                 tp: int = 1):
         self.cfg = cfg
         self.params = params
         self.paged = paged
         self.chunked = chunked
         self.page_tokens = page_tokens
         self.tp = int(tp)
-        self.interpret = interpret
         self.stats = {"token_fetches": 0, "tokens_fetched": 0}
         self.bus = None     # MetricsBus, attached by the Engine facade
         self.tracer = trace.null_tracer()   # Tracer, rebound by the facade
@@ -112,8 +110,15 @@ class Executor:
                 f"tp={self.tp} must divide the kv-head count ({cfg.n_kv}): "
                 "KV pages shard along the kv-head axis")
         self.mesh = sharding.tp_mesh(self.tp) if self.tp > 1 else None
+        if self.mesh is not None:
+            # weights are replicated on every shard: place them once, not
+            # on every dispatch
+            self.params = jax.device_put(params, NamedSharding(self.mesh, P()))
+        devices = (self.mesh.devices.flat if self.mesh is not None
+                   else jax.devices()[:1])
+        self.interpret = all(d.platform == "cpu" for d in devices)
         # interpret changes the compiled artifact, so it keys the cache too
-        key = (cfg, page_tokens, self.tp, interpret)
+        key = (cfg, page_tokens, self.tp, self.interpret)
         if paged:
             self._decode = _cached_region(
                 "paged_decode", key, self._make_paged_decode)
@@ -141,8 +146,8 @@ class Executor:
         if self.mesh is None:
             return fn
         in_specs = (P(),) * n_pre + (_PAGES_SPEC,) + (P(),) * n_post
-        return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=(P(), _PAGES_SPEC), check_rep=False)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=(P(), _PAGES_SPEC), check_vma=False)
 
     def _make_paged_decode(self):
         tp_axis = sharding.TP_AXIS if self.mesh is not None else None
@@ -255,6 +260,18 @@ class Executor:
                                      length)
             self._note_dispatch("prefill_slot")
             return out
+
+    def compile_decode(self, pool):
+        """Compile the paged decode region at ``pool``'s shapes. The
+        result's ``as_text()`` is what the device runs: a Pallas kernel
+        compiled by Mosaic appears in it as a ``tpu_custom_call``."""
+        n = len(pool.lengths)
+        args = (jnp.zeros((n, 1), jnp.int32), pool.pages,
+                jnp.asarray(pool.device_page_tables()),
+                jnp.zeros((n,), jnp.int32), jnp.zeros((n,), bool))
+        key = tuple((a.shape, str(a.dtype)) for a in jax.tree.leaves(args))
+        with self._mesh_ctx():
+            return self._decode.lower_compile(self.params, *args, key=key)[1]
 
     # -- pool placement ----------------------------------------------------
     def shard_pool(self, pool) -> None:

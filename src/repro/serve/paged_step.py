@@ -32,9 +32,11 @@ head ``h = k·G + g`` groups query heads per kv head), scatters and attends
 only its local page slice, and a single ``all_gather`` of the per-head
 partial outputs rebuilds the full head dimension before the (replicated)
 output projection. No cross-shard *reduction* ever happens — the gather is
-a pure concatenation — so tp=N greedy streams are bit-identical to tp=1
-(asserted in tests/test_scheduler_properties.py and
-benchmarks/bench_tensor_parallel.py).
+a pure concatenation — so on the CPU tp=N greedy streams are
+bit-identical to tp=1 (asserted in tests/test_scheduler_properties.py and
+benchmarks/bench_tensor_parallel.py). On TPU v5e chips they differed
+(cause not yet traced), and ``chip_smoke.py --tp 2`` holds both to the
+float32 reference instead.
 """
 from __future__ import annotations
 
@@ -260,14 +262,15 @@ def _paged_gqa_layer(p, x, pages, page_table, lengths, active,
 
 
 def make_paged_decode_step(cfg: transformer.ModelConfig, page_tokens: int,
-                           interpret: bool = True, tp_axis=None):
+                           *, interpret: bool, tp_axis=None):
     """Returns decode_step(params, tokens, pages, page_table, lengths, active)
     -> (logits [B, vocab], new pages).
 
     tokens: [B, 1] int32 (last sampled token per slot); pages: the
     PagedCachePool.pages pytree; page_table: [B, max_pages] int32;
     lengths: [B] int32 valid KV rows (the new token's write position);
-    active: [B] bool slot-occupancy mask.
+    active: [B] bool slot-occupancy mask. ``interpret`` runs the attention
+    kernel in the Pallas interpreter (CPU) instead of compiling it (TPU).
 
     With ``tp_axis`` set, the returned function must be called under
     ``shard_map`` over that mesh axis with pages sharded on their kv-head
@@ -385,7 +388,7 @@ def _paged_gqa_prefill_layer(p, x, pages, page_table, start,
 
 
 def make_paged_prefill_chunk_step(cfg: transformer.ModelConfig,
-                                  page_tokens: int, interpret: bool = True,
+                                  page_tokens: int, *, interpret: bool,
                                   tp_axis=None):
     """Returns prefill_chunk(params, tokens, pages, page_table, start)
     -> (last_logits [1, vocab], new pages) — the chunked-prefill TargetRegion.
@@ -396,7 +399,7 @@ def make_paged_prefill_chunk_step(cfg: transformer.ModelConfig,
     admission); start: scalar int32 chunk offset — traced, so one compile
     serves every offset of a given chunk size. The returned logits are the
     chunk's last position; the engine samples from them only when the chunk
-    completes the prompt.
+    completes the prompt. ``interpret`` as in :func:`make_paged_decode_step`.
     """
 
     def prefill_chunk(params, tokens, pages, page_table, start):

@@ -70,8 +70,9 @@ def test_serve_driver_cli(mode, tmp_path):
     env = dict(os.environ,
                PYTHONPATH=os.path.join(os.path.dirname(__file__), "..", "src"))
     trace_out = str(tmp_path / "serve.trace.json")
-    cmd = [sys.executable, "-m", "repro.launch.serve", "--requests", "3",
-           "--slots", "2", "--max-new", "3", "--max-seq", "32"]
+    cmd = [sys.executable, "-m", "repro.launch.serve", "--smoke",
+           "--requests", "3", "--slots", "2", "--max-new", "3",
+           "--max-seq", "32"]
     if mode == "paged":
         cmd += ["--paged", "--page-tokens", "8"]
     elif mode == "tiered":
@@ -231,3 +232,35 @@ def test_validate_bench_schema_roundtrip(tmp_path):
     assert set(SCHEMAS) == {"tiering", "chunked_prefill", "prefix_cache",
                             "tensor_parallel", "slo", "trace", "overlap",
                             "fleet", "kv_quant"}
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_location(tmp_path, env_dir):
+    """Entry points keep JAX's persistent compilation cache where
+    JAX_COMPILATION_CACHE_DIR says, and otherwise at the fixed in-checkout
+    directory (never a temporary one)."""
+    repo = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    script = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.launch.compile_cache import enable_compile_cache\n"
+        "path = enable_compile_cache()\n"
+        "assert jax.config.jax_compilation_cache_dir == path\n"
+        "print(path)\n")
+    if env_dir:    # cache even a fast compile, then look for the entry
+        script += (
+            "jax.config.update("
+            "'jax_persistent_cache_min_compile_time_secs', 0)\n"
+            "jax.jit(lambda x: x * 2 + 1)(jnp.arange(4.0)).block_until_ready()\n")
+    r = subprocess.run([sys.executable, "-c", script], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+    path = r.stdout.strip().splitlines()[-1]
+    if env_dir:
+        assert path == str(tmp_path)
+        assert os.listdir(tmp_path)
+    else:
+        assert path == os.path.join(os.path.realpath(repo), ".jax_cache")

@@ -242,7 +242,8 @@ def test_paged_flash_decode_vs_ref(B, H, K, hd, pt):
     lengths = rng.integers(1, 160, B).astype(np.int32)
     kp, vp, table = _paged_setup(rng, B, K, hd, pt, n_pages=96,
                                  lengths=lengths)
-    out = pda.paged_flash_decode(q, kp, vp, table, jnp.asarray(lengths))
+    out = pda.paged_flash_decode(q, kp, vp, table, jnp.asarray(lengths),
+                                 interpret=True)
     k_dense = pda.gather_pages(kp, table)
     v_dense = pda.gather_pages(vp, table)
     exp = ref.decode_attention(q, k_dense, v_dense, jnp.asarray(lengths))
@@ -259,7 +260,8 @@ def test_paged_flash_decode_page_boundary_lengths():
     lengths = np.array([1, pt, 2 * pt, 3 * pt - 1], np.int32)
     kp, vp, table = _paged_setup(rng, B, K, hd, pt, n_pages=32,
                                  lengths=lengths)
-    out = pda.paged_flash_decode(q, kp, vp, table, jnp.asarray(lengths))
+    out = pda.paged_flash_decode(q, kp, vp, table, jnp.asarray(lengths),
+                                 interpret=True)
     exp = pda.paged_decode_attention_ref(q, kp, vp, table, jnp.asarray(lengths))
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=2e-3,
                                atol=2e-3)
@@ -277,7 +279,8 @@ def test_paged_matches_dense_flash_decode():
                                  lengths=lengths)
     k_dense = pda.gather_pages(kp, table)
     v_dense = pda.gather_pages(vp, table)
-    out_paged = pda.paged_flash_decode(q, kp, vp, table, jnp.asarray(lengths))
+    out_paged = pda.paged_flash_decode(q, kp, vp, table, jnp.asarray(lengths),
+                                       interpret=True)
     out_dense = da.flash_decode(q, k_dense, v_dense, jnp.asarray(lengths),
                                 block_k=pt)
     np.testing.assert_allclose(np.asarray(out_paged), np.asarray(out_dense),
@@ -310,7 +313,8 @@ def test_paged_flash_prefill_vs_oracle(C, start):
                                    max_pages=6)
     q = jnp.asarray(rng.standard_normal((C, H, hd)).astype(np.float32))
     out = ppa.paged_flash_prefill(q, kp, vp, table,
-                                  jnp.asarray(start, jnp.int32))
+                                  jnp.asarray(start, jnp.int32),
+                                  interpret=True)
     exp = ppa.paged_prefill_attention_ref(q, kp, vp, table, start)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=2e-3,
                                atol=2e-3)
@@ -348,7 +352,8 @@ def test_paged_prefill_chunked_matches_single_shot_ref(chunks):
         vp = scatter_chunk(vp, v_full[sl], table,
                            jnp.asarray(start, jnp.int32), pt)
         outs.append(ppa.paged_flash_prefill(q_full[sl], kp, vp, table,
-                                            jnp.asarray(start, jnp.int32)))
+                                            jnp.asarray(start, jnp.int32),
+                                            interpret=True))
         start += C
     got = jnp.concatenate(outs, axis=0)                      # [S, H, hd]
 
@@ -379,7 +384,8 @@ def test_paged_prefill_chunk_boundary_mid_page():
     for C, start in [(6, 0), (7, 6)]:     # 13 tokens split mid-page at 6
         q = jnp.asarray(rng.standard_normal((C, H, hd)).astype(np.float32))
         out = ppa.paged_flash_prefill(q, kp, vp, table,
-                                      jnp.asarray(start, jnp.int32))
+                                      jnp.asarray(start, jnp.int32),
+                                      interpret=True)
         exp = ppa.paged_prefill_attention_ref(q, kp, vp, table, start)
         np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                    rtol=2e-3, atol=2e-3)
@@ -407,7 +413,7 @@ def test_paged_flash_decode_quantized_matches_ref():
                                  lengths=lengths)
     kq, ks, vq, vs = _quantize_setup(kp, vp)
     out = pda.paged_flash_decode(q, kq, vq, table, jnp.asarray(lengths),
-                                 k_scale=ks, v_scale=vs)
+                                 k_scale=ks, v_scale=vs, interpret=True)
     exp = pda.paged_decode_attention_ref(q, kq, vq, table,
                                          jnp.asarray(lengths),
                                          k_scale=ks, v_scale=vs)
@@ -415,7 +421,8 @@ def test_paged_flash_decode_quantized_matches_ref():
                                atol=2e-3)
     # and the quantized result tracks the full-precision one within the
     # int8 error budget (absmax/127 per element on K and V)
-    full = pda.paged_flash_decode(q, kp, vp, table, jnp.asarray(lengths))
+    full = pda.paged_flash_decode(q, kp, vp, table, jnp.asarray(lengths),
+                                  interpret=True)
     assert float(jnp.max(jnp.abs(out - full))) < 0.15
 
 
@@ -429,13 +436,14 @@ def test_paged_flash_prefill_quantized_matches_ref():
     q = jnp.asarray(rng.standard_normal((C, H, hd)).astype(np.float32))
     out = ppa.paged_flash_prefill(q, kq, vq, table,
                                   jnp.asarray(start, jnp.int32),
-                                  k_scale=ks, v_scale=vs)
+                                  k_scale=ks, v_scale=vs, interpret=True)
     exp = ppa.paged_prefill_attention_ref(q, kq, vq, table, start,
                                           k_scale=ks, v_scale=vs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=2e-3,
                                atol=2e-3)
     full = ppa.paged_flash_prefill(q, kp, vp, table,
-                                   jnp.asarray(start, jnp.int32))
+                                   jnp.asarray(start, jnp.int32),
+                                   interpret=True)
     assert float(jnp.max(jnp.abs(out - full))) < 0.15
 
 
@@ -451,7 +459,7 @@ def test_quantized_kernels_require_scale_pairs():
     ks = jnp.ones((4, K), jnp.float32)
     with pytest.raises(ValueError):
         pda.paged_flash_decode(q, kp, vp, table, jnp.asarray(lengths),
-                               k_scale=ks)
+                               k_scale=ks, interpret=True)
     with pytest.raises(ValueError):
         ppa.paged_flash_prefill(q[0], kp, vp, table[0], jnp.asarray(0),
-                                v_scale=ks)
+                                v_scale=ks, interpret=True)

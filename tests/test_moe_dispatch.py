@@ -16,7 +16,7 @@ from repro.models import moe
 from repro.models.blocks import split_params
 from repro.parallel import sharding as shlib
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = shlib.auto_mesh((2, 4), ("data", "model"))
 cfg = moe.MoeConfig(d_model=32, n_experts=8, top_k=2, d_ff=16, n_shared=1,
                     capacity_factor=2.0)
 p, _ = split_params(moe.init_moe(jax.random.PRNGKey(0), cfg))

@@ -303,7 +303,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.parallel.pipeline import gpipe
 
-mesh = jax.make_mesh((4,), ("stage",))
+mesh = jax.make_mesh((4,), ("stage",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 L, D, M, mb = 8, 16, 8, 4
 rng = np.random.default_rng(0)
 ws = jnp.asarray(rng.standard_normal((L, D, D)).astype(np.float32) * 0.3)
