@@ -166,8 +166,6 @@ def run(smoke: bool = True, arch: str = "qwen2-0.5b", n_slots: int = 2,
     events = eng_t.tracer.chrome_trace()["traceEvents"]
     dma_windows = sum(1 for e in events
                      if e.get("ph") == "b" and e["name"].endswith("_dma"))
-    device_windows = sum(1 for e in events
-                         if e.get("ph") == "b" and e["name"] == "device_step")
     assert dma_windows > 0, "oversubscribed tiered run must record swap DMA"
     trace_path = eng_t.trace_export(
         os.path.join(REPO_ROOT, "BENCH_serve.trace.json"))
@@ -195,7 +193,7 @@ def run(smoke: bool = True, arch: str = "qwen2-0.5b", n_slots: int = 2,
         "stall_pct_dma": summary["stall_pct_dma"],
         "stall_pct_shadowed": summary["stall_pct_shadowed"],
         "stall_pct_other": summary["stall_pct_other"],
-        "dma_windows": dma_windows, "device_windows": device_windows,
+        "dma_windows": dma_windows,
     }
     payload = {
         "arch": arch, "hot_pages": hot_pages, "page_tokens": page_tokens,
@@ -218,7 +216,7 @@ def run(smoke: bool = True, arch: str = "qwen2-0.5b", n_slots: int = 2,
           f"{summary['stall_pct_shadowed']:.1f}/"
           f"{summary['stall_pct_other']:.1f} (sched/fetch/dma/shadowed/other)")
     print(f"# closure worst err {worst_err:.4f}% (tol {CLOSURE_TOL_PCT}%); "
-          f"{dma_windows} dma windows, {device_windows} device windows; "
+          f"{dma_windows} dma windows; "
           f"streams bit-identical traced/untraced/fake-clock; "
           f"exported {os.path.basename(trace_path)}; wrote {path}")
     return payload

@@ -111,7 +111,7 @@ ENGINE_NUM_KEYS = {
     "trace": ("completed", "tokens", "wall_s", "iterations", "events",
               "dropped", "stall_pct_schedule", "stall_pct_fetch",
               "stall_pct_dma", "stall_pct_shadowed", "stall_pct_other",
-              "dma_windows", "device_windows"),
+              "dma_windows"),
     "overlap": ("completed", "tokens", "wall_s", "iterations",
                 "noncompute_pct", "stall_pct_schedule", "stall_pct_fetch",
                 "stall_pct_dma", "stall_pct_shadowed", "stall_pct_other",

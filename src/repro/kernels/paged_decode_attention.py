@@ -156,6 +156,7 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * K, G, hd), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(*inputs)
     return out.reshape(B, H, hd)
 

@@ -160,6 +160,7 @@ def paged_flash_prefill(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((K, C, G, hd), q.dtype),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(*inputs)
     return jnp.transpose(out, (1, 0, 2, 3)).reshape(C, H, hd)
 

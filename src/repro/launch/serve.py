@@ -67,8 +67,8 @@ typed verdicts at the end of the run.
 
 Execution tracing (PR 7): ``--trace out.json`` records a span timeline of
 the whole run (engine iterations and their schedule/policy/dispatch/fetch
-phases, per-request lifecycle tracks, async device windows and swap DMA
-transfers) and exports it as Chrome trace-event JSON — open the file in
+phases, per-request lifecycle tracks, and swap DMA transfers) and exports
+it as Chrome trace-event JSON — open the file in
 Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``. The run also
 prints a ``[serve:trace]`` stall-attribution line decomposing iteration
 wall time into schedule/fetch/dma/other. ``--trace-buffer N`` bounds the

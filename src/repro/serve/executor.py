@@ -101,7 +101,6 @@ class Executor:
         self.stats = {"token_fetches": 0, "tokens_fetched": 0}
         self.bus = None     # MetricsBus, attached by the Engine facade
         self.tracer = trace.null_tracer()   # Tracer, rebound by the facade
-        self._inflight: List[Tuple[str, float]] = []  # open device windows
         if self.tp > 1 and not paged:
             raise ValueError("tensor parallelism requires the paged serving "
                              "path (dense slot caches are not head-sharded)")
@@ -155,12 +154,12 @@ class Executor:
             self.cfg, self.page_tokens, interpret=self.interpret,
             tp_axis=tp_axis)
 
-        def sampled(params, tokens, pages, page_table, lengths, active):
+        def decode_step(params, tokens, pages, page_table, lengths, active):
             logits, pages = base(params, tokens, pages, page_table, lengths,
                                  active)
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), pages
 
-        return self._shard_mapped(sampled, n_pre=2, n_post=3)
+        return self._shard_mapped(decode_step, n_pre=2, n_post=3)
 
     def _make_prefill_chunk(self):
         tp_axis = sharding.TP_AXIS if self.mesh is not None else None
@@ -168,34 +167,34 @@ class Executor:
             self.cfg, self.page_tokens, interpret=self.interpret,
             tp_axis=tp_axis)
 
-        def sampled(params, tokens, pages, table_row, start):
+        def prefill_chunk_step(params, tokens, pages, table_row, start):
             logits, pages = base(params, tokens, pages, table_row, start)
             return jnp.argmax(logits[0]).astype(jnp.int32), pages
 
-        return self._shard_mapped(sampled, n_pre=2, n_post=2)
+        return self._shard_mapped(prefill_chunk_step, n_pre=2, n_post=2)
 
     def _make_prefill_dense(self):
         base = steps.make_prefill_step(self.cfg)
 
-        def sampled(params, tokens, caches):
+        def prefill_dense_step(params, tokens, caches):
             logits, caches = base(params, tokens, caches)   # [B, 1, vocab]
             return jnp.argmax(logits[0, -1]).astype(jnp.int32), caches
 
-        return sampled
+        return prefill_dense_step
 
     def _make_dense_decode(self):
         base = steps.make_decode_step(self.cfg)
 
-        def sampled(params, tokens, caches, cache_pos):
+        def decode_dense_step(params, tokens, caches, cache_pos):
             logits, caches = base(params, tokens, caches, cache_pos)
             return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), caches
 
-        return sampled
+        return decode_dense_step
 
     def _make_prefill_slot(self):
         cfg = self.cfg
 
-        def sampled(params, tokens, caches, slot, length):
+        def prefill_slot_step(params, tokens, caches, slot, length):
             logits, new_caches, _ = transformer.forward(
                 params, tokens, cfg, caches=caches,
                 cache_pos=jnp.zeros((), jnp.int32), mode="prefill")
@@ -211,54 +210,46 @@ class Executor:
             return (jnp.argmax(logits[slot, length - 1]).astype(jnp.int32),
                     merged)
 
-        return sampled
+        return prefill_slot_step
 
     # -- dispatch (async — the host thread continues immediately) ----------
-    def _note_dispatch(self, kind: str) -> None:
-        """Open a device window: jax dispatch is async, so the device is
-        (at least potentially) busy from here until this iteration's host
-        values land in ``fetch_token_ids`` — which closes every open window
-        with observed timestamps (span gaps, not guesses)."""
-        if self.tracer.enabled:
-            self._inflight.append((kind, self.tracer.now()))
-        # host spans entered from here until the next fetch are candidates
-        # for the "shadowed" stall bucket (self-gated when tracing is off)
-        self.tracer.device_dispatch()
-
+    # Each dispatch signals the tracer that a device step is in flight: host
+    # spans entered from there until the next fetch are candidates for the
+    # "shadowed" stall bucket (self-gated when tracing is off).
     def decode_paged(self, tokens, pages, page_table, lengths, active):
-        with self.tracer.span("dispatch", kind="decode_paged"):
+        with self.tracer.dispatch("decode"):
             with self._mesh_ctx():
                 out = self._decode(self.params, tokens, pages, page_table,
                                    lengths, active)
-            self._note_dispatch("decode_paged")
+            self.tracer.device_dispatch()
             return out
 
     def prefill_chunk(self, tokens, pages, table_row, start):
-        with self.tracer.span("dispatch", kind="prefill_chunk"):
+        with self.tracer.dispatch("prefill_chunk"):
             with self._mesh_ctx():
                 out = self._prefill_chunk(self.params, tokens, pages,
                                           table_row, start)
-            self._note_dispatch("prefill_chunk")
+            self.tracer.device_dispatch()
             return out
 
     def prefill_dense(self, tokens, caches):
-        with self.tracer.span("dispatch", kind="prefill_dense"):
+        with self.tracer.dispatch("prefill_dense"):
             with self._mesh_ctx():
                 out = self._prefill_dense(self.params, tokens, caches)
-            self._note_dispatch("prefill_dense")
+            self.tracer.device_dispatch()
             return out
 
     def decode_dense(self, tokens, caches, cache_pos):
-        with self.tracer.span("dispatch", kind="decode_dense"):
+        with self.tracer.dispatch("decode_dense"):
             out = self._decode(self.params, tokens, caches, cache_pos)
-            self._note_dispatch("decode_dense")
+            self.tracer.device_dispatch()
             return out
 
     def prefill_slot(self, tokens, caches, slot, length):
-        with self.tracer.span("dispatch", kind="prefill_slot"):
+        with self.tracer.dispatch("prefill_slot"):
             out = self._prefill_slot(self.params, tokens, caches, slot,
                                      length)
-            self._note_dispatch("prefill_slot")
+            self.tracer.device_dispatch()
             return out
 
     def compile_decode(self, pool):
@@ -290,9 +281,9 @@ class Executor:
         self.bus = bus
 
     def bind_tracer(self, tracer) -> None:
-        """Attach the engine's Tracer: dispatches open ``dispatch`` spans +
-        async ``device_step`` windows, and ``fetch_token_ids`` wraps the one
-        device→host sync in a ``fetch_tokens`` span (observe-only)."""
+        """Attach the engine's Tracer: dispatches open ``dispatch`` spans,
+        and ``fetch_token_ids`` wraps the one device→host sync in a
+        ``fetch_tokens`` span (observe-only)."""
         self.tracer = tracer
 
     # -- the one device→host transfer --------------------------------------
@@ -310,15 +301,6 @@ class Executor:
         with self.tracer.span("fetch_tokens", arrays=len(arrays)):
             host = np.asarray(joined)
         self.tracer.device_landed()
-        if self._inflight:
-            # the host values landed: every window opened since the last
-            # fetch is now known to have completed — close them at observed
-            # time on the device track
-            t_end = self.tracer.now()
-            for kind, t_begin in self._inflight:
-                self.tracer.async_span("device", "device_step", t_begin,
-                                       t_end, kind=kind)
-            self._inflight.clear()
         self.stats["tokens_fetched"] += int(host.size)
         if self.bus is not None:
             self.bus.set_total("token_fetches", self.stats["token_fetches"])

@@ -65,7 +65,9 @@ request streams.
 
 Tracing (PR 7): the scheduler is also the tracing root — every ``step()``
 runs inside a :class:`repro.serve.trace.Tracer` ``iteration`` span, with
-``schedule``/``policy``/``prefill_chunk`` phase spans below it and request
+``schedule``/``policy``/``commit``/``decode_inputs``/``prefill_chunk``
+phase spans below it (``submit`` spans between iterations), all of them
+also on the JAX profiler's clock as ``serve.*`` annotations, and request
 lifecycle state recorded at every transition (``queued → admitted →
 prefill → decode → finished/shed/preempted → resumed``). The tracer's
 injected clock is the scheduler's ONLY time source (``self.tracer.now()``
@@ -176,6 +178,7 @@ class Scheduler:
         self._fetch_queue: List[Tuple[Any, Callable]] = []
         self._commit_queue: List[Tuple[Any, Callable]] = []  # overlap: prev it.
         self._finished: List[Request] = []
+        self._iterations = 0         # step() calls: the profiler's step number
         if self.paged:
             self._admit_stalled = False
             self._pending_swapins: List[Tuple[Request, Any]] = []
@@ -199,15 +202,16 @@ class Scheduler:
 
     # -- host API ----------------------------------------------------------
     def submit(self, req: Request) -> bool:
-        req.t_submit = self.tracer.now()
-        req.t_first = 0.0
-        req.prefill_pos = 0
-        req.tokens_out = []
-        req.t_tokens = []
-        req.verdict = None
-        self.bus.inc("requests_submitted")
-        self.tracer.request_state(req.seq_id, "queued")
-        return self.mailbox.put(req)
+        with self.tracer.span("submit"):
+            req.t_submit = self.tracer.now()
+            req.t_first = 0.0
+            req.prefill_pos = 0
+            req.tokens_out = []
+            req.t_tokens = []
+            req.verdict = None
+            self.bus.inc("requests_submitted")
+            self.tracer.request_state(req.seq_id, "queued")
+            return self.mailbox.put(req)
 
     @property
     def idle(self) -> bool:
@@ -240,7 +244,7 @@ class Scheduler:
         dispatch, each phase flushed once. Returns the requests that
         finished this iteration."""
         self._finished = []
-        with self.tracer.iteration():
+        with self.tracer.iteration(self._iterations):
             self._policy_pass()
             decoded = False
             if self.chunked:
@@ -269,6 +273,7 @@ class Scheduler:
                 # (the overlapped loop prefetches inside its shadow phase)
                 self._start_prefetch()
             self._publish_metrics()
+        self._iterations += 1
         self._publish_stall()
         return self._finished
 
@@ -395,8 +400,9 @@ class Scheduler:
             return
         queue, self._fetch_queue = self._fetch_queue, []
         vals = self.executor.fetch_token_ids([a for a, _ in queue])
-        for (_, consumer), v in zip(queue, vals):
-            consumer(v)
+        with self.tracer.span("commit"):
+            for (_, consumer), v in zip(queue, vals):
+                consumer(v)
 
     def _flush_commit(self) -> None:
         """Overlap-mode commit point: materialise the PREVIOUS iteration's
@@ -407,8 +413,9 @@ class Scheduler:
             return
         queue, self._commit_queue = self._commit_queue, []
         vals = self.executor.fetch_token_ids([a for a, _ in queue])
-        for (_, consumer), v in zip(queue, vals):
-            consumer(v)
+        with self.tracer.span("commit"):
+            for (_, consumer), v in zip(queue, vals):
+                consumer(v)
         # the in-flight map is only meaningful between a dispatch and its
         # commit (the shadow COW pre-fork keys on it) — drop it now so a
         # dispatch-free iteration can't leave a stale pair behind
@@ -757,29 +764,31 @@ class Scheduler:
             self._finish_pending_swapin()
         if slots is None:
             slots = sorted(self.active)
-        B = self.pool.max_batch
-        toks = np.zeros((B, 1), np.int32)
-        mask = np.zeros(B, bool)
-        for slot in slots:
-            req = self.active[slot]
-            toks[slot, 0] = req.tokens_out[-1]
-            mask[slot] = True
-            # a shared page at the write position is COW-forked before the
-            # divergent write (first decode after a full-prefix hit, or a
-            # donor decoding into its cache-shared tail page); the fork page
-            # was pre-reserved, so neither call below can fail
-            if self.prefix is not None and self.pool.cow_unshare(
-                    slot, int(self.pool.lengths[slot])):
-                self.stats["cow_forks"] += 1
-            # map the write position (lengths[slot]) before dispatch; the
-            # decode reservation guarantees this never fails
-            self.pool.ensure(slot, int(self.pool.lengths[slot]) + 1)
-        tables = jnp.asarray(self.pool.device_page_tables())
-        lengths = jnp.asarray(self.pool.lengths.astype(np.int32))
-        # mid-prefill / unpromoted slots are resident but must not decode
-        active = jnp.asarray(mask)
+        with self.tracer.span("decode_inputs"):
+            B = self.pool.max_batch
+            toks = np.zeros((B, 1), np.int32)
+            mask = np.zeros(B, bool)
+            for slot in slots:
+                req = self.active[slot]
+                toks[slot, 0] = req.tokens_out[-1]
+                mask[slot] = True
+                # a shared page at the write position is COW-forked before
+                # the divergent write (first decode after a full-prefix hit,
+                # or a donor decoding into its cache-shared tail page); the
+                # fork page was pre-reserved, so neither call below can fail
+                if self.prefix is not None and self.pool.cow_unshare(
+                        slot, int(self.pool.lengths[slot])):
+                    self.stats["cow_forks"] += 1
+                # map the write position (lengths[slot]) before dispatch;
+                # the decode reservation guarantees this never fails
+                self.pool.ensure(slot, int(self.pool.lengths[slot]) + 1)
+            tables = jnp.asarray(self.pool.device_page_tables())
+            lengths = jnp.asarray(self.pool.lengths.astype(np.int32))
+            # mid-prefill / unpromoted slots are resident but must not decode
+            active = jnp.asarray(mask)
+            toks = jnp.asarray(toks)
         ids_dev, self.pool.pages = self.executor.decode_paged(
-            jnp.asarray(toks), self.pool.pages, tables, lengths, active)
+            toks, self.pool.pages, tables, lengths, active)
         self.stats["decode_steps"] += 1
         self.stats["decode_tokens"] += len(slots)
         self.stats["batch_occupancy"].append(len(slots) / B)

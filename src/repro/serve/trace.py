@@ -5,19 +5,32 @@ measurement — its double-buffered DMA headline was only tunable because
 stall *cycles* were measurable per phase. The serving analogue is this
 tracer: the metrics bus (serve/metrics.py) records *what* happened each
 iteration (counters, histograms); this module records *where wall time
-went*, microsecond by microsecond, so the overlapped-execution work can
-drive the measured stalls to zero instead of guessing at them.
+went*, so the overlapped-execution work can drive the measured stalls to
+zero instead of guessing at them.
+
+**Two sinks.** Every phase span goes to the JAX profiler as a
+``jax.profiler.TraceAnnotation`` named ``serve.<phase>`` (the iteration
+root as a ``StepTraceAnnotation`` ``serve.iteration`` carrying the
+scheduler's step number), whether or not the tracer is enabled. Outside a
+profiler session such an annotation costs about a microsecond and records
+nothing; inside one (``jax.profiler.trace(dir)``) the spans land on the
+profiler's host plane beside the device's ops, so each device-idle gap can
+be charged to the host phase that was running in it
+(``bench/serve_spans.py`` does this, after checking the profiler's sync of
+the host and device clocks against the decode steps). The profiler session is the only
+switch. The tracer's own ring buffer, below, records only when enabled.
 
 Two span families share one bounded ring buffer:
 
   * **Per-iteration phase spans** on the engine track — ``schedule``,
-    ``policy``, ``dispatch``, ``fetch_tokens``, ``swap_wait``, ``cow_copy``,
-    ``prefill_chunk`` — nested inside an ``iteration`` root span that the
-    scheduler opens around each ``step()``. Device-side work (the async
-    dispatch window, DMA transfers in flight) is recorded as **async
-    events** on separate ``device``/``dma`` tracks from *observed*
-    timestamps (dispatch→host-landing, `TransferHandle.t_start`→`t_done`),
-    so overlap shows as real span gaps, never as guessed durations.
+    ``policy``, ``commit``, ``decode_inputs``, ``dispatch``,
+    ``fetch_tokens``, ``swap_wait``, ``cow_copy``, ``prefill_chunk`` —
+    nested inside an ``iteration`` root span that the scheduler opens
+    around each ``step()``; ``submit`` spans sit between iterations. DMA
+    transfers are recorded as **async events** on a separate ``dma`` track
+    from *observed* timestamps (`TransferHandle.t_start`→`t_done`), so
+    overlap shows as real span gaps, never as guessed durations. Device
+    time is not recorded here: the profiler's device plane has it.
   * **Per-request lifecycle spans**, one track per ``seq_id`` — a state
     machine ``queued → prefill → decode → finished`` with ``preempted`` /
     re-``queued`` detours, ``admitted``/``resumed`` instants, and terminal
@@ -31,8 +44,9 @@ exclusive by construction. Self times map onto five buckets — ``schedule``
 (schedule + policy spans), ``fetch`` (the one device→host token sync),
 ``dma`` (blocking swap-DMA waits), ``shadowed`` (host work performed while
 a dispatched device step was still in flight — overlapped, not a stall),
-``other`` (dispatch, chunk/COW host work, iteration residue) — which
-therefore sum to the iteration's wall time *exactly*, not approximately.
+``other`` (dispatch, commit, decode inputs, chunk/COW host work, iteration
+residue) — which therefore sum to the iteration's wall time *exactly*, not
+approximately.
 The ``shadowed`` relabel is driven by the executor's
 :meth:`Tracer.device_dispatch`/:meth:`Tracer.device_landed` signals: a host
 span that opens after a dispatch and closes before that step's results land
@@ -44,9 +58,9 @@ metrics bus; :meth:`Tracer.stall_summary` aggregates the run.
 
 Export is Chrome trace-event JSON (:meth:`Tracer.chrome_trace` /
 :meth:`Tracer.export`): ``ph:"X"`` complete events with µs ``ts``/``dur``,
-``ph:"b"``/``"e"`` async pairs for device/DMA windows, ``ph:"i"`` instants,
+``ph:"b"``/``"e"`` async pairs for DMA windows, ``ph:"i"`` instants,
 and ``ph:"M"`` thread-name metadata — load the file in Perfetto (or
-chrome://tracing) and the engine/device/dma/request tracks line up on one
+chrome://tracing) and the engine/dma/request tracks line up on one
 timeline (docs/ARCHITECTURE.md shows how to read it).
 
 Ownership boundaries & invariants (tests/test_trace.py):
@@ -55,11 +69,12 @@ Ownership boundaries & invariants (tests/test_trace.py):
     executor state; instrumented code paths read the clock and append
     records, full stop. Token streams and ``stats_summary()`` are identical
     with tracing on or off.
-  * **Disabled ⇒ null-object no-ops** (the MetricsBus pattern):
-    ``span()``/``iteration()`` return one shared inert context manager,
-    lifecycle/async records return immediately, and no stall histograms
-    are published — a disabled-tracer engine is bit-identical (streams AND
-    ``metrics_snapshot()``) to one that never constructed a tracer.
+  * **Disabled ⇒ no records**: ``span()``/``iteration()``/``dispatch()``
+    return the bare profiler annotation (no clock read, no allocation in
+    the tracer), lifecycle/async records return immediately, and no stall
+    histograms are published — a disabled-tracer engine is bit-identical
+    (streams AND ``metrics_snapshot()``) to one that never constructed a
+    tracer.
   * **One clock.** ``now()`` delegates to the injected monotonic clock
     (default ``time.perf_counter``) whether or not tracing is enabled — the
     scheduler routes ALL of its timing (submit stamps, TTFT/ITL, policy
@@ -76,6 +91,8 @@ import collections
 import json
 import time
 from typing import Any, Callable, Deque, Dict, List, Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 # ring-buffer default: ~64k events ≈ a few thousand iterations of a busy
 # engine — deep enough for any bench window, bounded on a long-running one
@@ -98,7 +115,6 @@ BUCKETS = ("schedule", "fetch", "dma", "shadowed", "other")
 
 # trace-track thread ids (pid is always 0 — one engine process)
 TID_ENGINE = 0
-TID_DEVICE = 1
 TID_DMA = 2
 TID_REQ_BASE = 100          # request seq_id s renders on tid 100 + s
 
@@ -106,43 +122,29 @@ TID_REQ_BASE = 100          # request seq_id s renders on tid 100 + s
 _TERMINAL = ("finished", "shed")
 
 
-class _NullSpan:
-    """Shared inert context manager for the disabled tracer (cf. the
-    MetricsBus null objects): entering/exiting costs two attribute lookups
-    and no allocation."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class _Span:
-    """One open phase span: context manager pushed on the tracer's stack.
+    """One open phase span: context manager pushed on the tracer's stack,
+    inside the profiler annotation ``ann`` of the same phase.
 
     ``child`` accumulates completed children's wall time so ``__exit__``
     can compute exclusive self time — the stall buckets sum to the
     iteration span exactly because every microsecond is counted once."""
 
-    __slots__ = ("tracer", "name", "args", "t0", "child", "is_iter",
+    __slots__ = ("tracer", "name", "args", "ann", "t0", "child", "is_iter",
                  "shadow0", "closes0")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any],
-                 is_iter: bool = False):
+                 ann: TraceAnnotation, is_iter: bool = False):
         self.tracer = tracer
         self.name = name
         self.args = args
+        self.ann = ann
         self.child = 0.0
         self.is_iter = is_iter
 
     def __enter__(self):
         tr = self.tracer
+        self.ann.__enter__()
         self.t0 = tr.clock()
         # device-busy snapshot: if work is in flight now and it has not
         # landed by __exit__, this span ran entirely under the device step
@@ -179,6 +181,7 @@ class _Span:
             tr._buckets = None
             tr._stall.append(entry)
             tr._last_iter = entry
+        self.ann.__exit__(*exc)
         return False
 
 
@@ -186,9 +189,10 @@ class Tracer:
     """Span-based execution tracer for one engine (see module docstring).
 
     ``enabled=False`` keeps ``now()`` working (the injected clock is the
-    serve layer's one timing source either way) but turns every recording
-    call into a no-op — the MetricsBus discipline, so measurement never
-    perturbs scheduling.
+    serve layer's one timing source either way) and the profiler
+    annotations of the phase spans, but turns every recording call into a
+    no-op — the MetricsBus discipline, so measurement never perturbs
+    scheduling.
     """
 
     def __init__(self, enabled: bool = True,
@@ -218,31 +222,43 @@ class Tracer:
 
     # -- phase spans -------------------------------------------------------
     def span(self, name: str, **args):
-        """Context manager for one engine-track phase span."""
+        """Context manager for one engine-track phase span; the profiler
+        sees it as ``serve.<name>`` with ``args`` as its metadata."""
+        ann = TraceAnnotation(f"serve.{name}", **args)
         if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, args)
+            return ann
+        return _Span(self, name, args, ann)
 
-    def iteration(self, **args):
-        """The per-step root span: opens a fresh stall-bucket accumulator,
+    def dispatch(self, kind: str):
+        """The ``dispatch`` phase span of one compiled step of ``kind``;
+        the profiler sees it as ``serve.dispatch.<kind>``."""
+        ann = TraceAnnotation(f"serve.dispatch.{kind}")
+        if not self.enabled:
+            return ann
+        return _Span(self, "dispatch", {"kind": kind}, ann)
+
+    def iteration(self, step_num: int = 0):
+        """The per-step root span (``serve.iteration``, a profiler step
+        numbered ``step_num``): opens a fresh stall-bucket accumulator,
         closes it into the stall log (``last_iteration``) on exit."""
+        ann = StepTraceAnnotation("serve.iteration", step_num=step_num)
         if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, "iteration", args, is_iter=True)
+            return ann
+        return _Span(self, "iteration", {}, ann, is_iter=True)
 
-    # -- async device / dma records (observed timestamps) ------------------
+    # -- async dma records (observed timestamps) ----------------------------
     def async_span(self, track: str, name: str, t_start: float,
                    t_end: float, **args) -> None:
-        """Record an async window on the ``device`` or ``dma`` track from
-        timestamps *observed* at the endpoints (dispatch / handle stamps) —
-        overlap with host spans shows as real gaps, never guesses."""
+        """Record an async window on the ``dma`` track from timestamps
+        *observed* at the endpoints (the transfer handle's stamps) — overlap
+        with host spans shows as real gaps, never guesses. ``track`` is the
+        event's category."""
         if not self.enabled:
             return
         self._async_id += 1
-        tid = TID_DMA if track == "dma" else TID_DEVICE
-        self._push({"ph": "b", "name": name, "tid": tid, "cat": track,
+        self._push({"ph": "b", "name": name, "tid": TID_DMA, "cat": track,
                     "t": t_start, "id": self._async_id, "args": args})
-        self._push({"ph": "e", "name": name, "tid": tid, "cat": track,
+        self._push({"ph": "e", "name": name, "tid": TID_DMA, "cat": track,
                     "t": t_end, "id": self._async_id, "args": {}})
 
     # -- device-busy signal (overlap attribution) ---------------------------
@@ -354,7 +370,7 @@ class Tracer:
         exported with ``args.partial = true`` — readers must not assume its
         child spans close it exactly. Over-marking is safe; under-marking
         would silently break the bucket-closure contract."""
-        names = {TID_ENGINE: "engine", TID_DEVICE: "device", TID_DMA: "dma"}
+        names = {TID_ENGINE: "engine", TID_DMA: "dma"}
         seen_tids = {ev["tid"] for ev in self.events}
         events: List[Dict[str, Any]] = [
             {"ph": "M", "name": "process_name", "pid": 0, "tid": 0,

@@ -85,7 +85,9 @@ def test_paged_kernel_compiles(one_chip, cfg, kernel, kv_dtype, tp):
         fn = paged_flash_prefill
     if kv_dtype == jnp.int8:
         args += [_sds((N_PAGES, K), jnp.float32, one_chip)] * 2
-    _compile(lambda *a: fn(*a, interpret=False), *args)
+    compiled = _compile(lambda *a: fn(*a, interpret=False), *args)
+    # the kernel keeps its name on the chip, so a profile can tell it apart
+    assert f"%paged_{kernel}_attention" in compiled.as_text()
 
 
 @pytest.mark.parametrize("step", ["decode", "chunk"])

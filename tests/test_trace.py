@@ -389,4 +389,32 @@ def test_traced_engine_stall_closure_and_export(tmp_path):
             "swap_wait"} <= names
     async_names = {e["name"] for e in doc["traceEvents"]
                    if e.get("ph") == "b"}
-    assert "device_step" in async_names and "swap_out_dma" in async_names
+    assert "swap_out_dma" in async_names
+
+
+def test_programs_carry_stable_names():
+    # a profile names each program jit_<function>, so the decode and the
+    # prefill-chunk steps are told apart (tests/test_tpu_compile.py checks
+    # the kernels' names on the chip)
+    eng = Engine(_CFG, _params(), config=EngineConfig(
+        n_slots=2, max_seq=32, chunked=True, token_budget=8,
+        cache=CacheConfig(paged=True, page_tokens=8)))
+    ex, pool = eng.executor, eng.pool
+    tables = jnp.asarray(pool.device_page_tables())
+    dec = ex._decode._jitted.lower(
+        ex.params, jnp.zeros((2, 1), jnp.int32), pool.pages, tables,
+        jnp.zeros((2,), jnp.int32), jnp.zeros((2,), bool)).as_text()
+    chunk = ex._prefill_chunk._jitted.lower(
+        ex.params, jnp.zeros((1, 5), jnp.int32), pool.pages, tables[0],
+        jnp.zeros((), jnp.int32)).as_text()
+    assert dec.startswith("module @jit_decode_step ")
+    assert chunk.startswith("module @jit_prefill_chunk_step ")
+
+
+def test_span_cost_script_times_each_case():
+    from benchmarks.span_cost import measure
+    got = measure(loops=50)
+    for side in ("off_us", "session_us"):
+        assert set(got[side]) == {"span", "span_meta", "dispatch",
+                                  "iteration"}
+        assert all(v > 0.0 for v in got[side].values())
