@@ -10,9 +10,9 @@ boundaries**: the query at global position ``start + i`` sees keys at
 positions ``<= start + i``, whether those keys were written by an earlier
 chunk (a different dispatch) or by this one.
 
-Kernel structure mirrors paged_flash_decode: grid (K, max_pages) with kv
-pages innermost and (m, l, acc) online-softmax scratch carried across them;
-the page-table walk happens in the BlockSpec index_map via scalar prefetch.
+Kernel structure: grid (K, max_pages) with kv pages innermost and (m, l,
+acc) online-softmax scratch carried across them; the page-table walk
+happens in the BlockSpec index_map via scalar prefetch.
 Two scalars ride along in the prefetch: the page table row and ``start`` (the
 chunk's global query offset) — the causal frontier is a *runtime* value, so
 one compiled kernel serves every chunk of a given size.

@@ -231,7 +231,8 @@ def _paged_setup(rng, B, K, hd, pt, n_pages, lengths):
 
 
 @pytest.mark.parametrize("B,H,K,hd", [(2, 8, 2, 64), (1, 4, 4, 128),
-                                      (3, 6, 3, 64), (2, 4, 1, 32)])
+                                      (3, 6, 3, 64), (2, 4, 1, 32),
+                                      (2, 24, 8, 128)])   # minitron: G 3
 @pytest.mark.parametrize("pt", [8, 16, 64])
 def test_paged_flash_decode_vs_ref(B, H, K, hd, pt):
     """Golden test over ragged lengths × GQA group counts × page sizes."""
@@ -265,6 +266,123 @@ def test_paged_flash_decode_page_boundary_lengths():
     exp = pda.paged_decode_attention_ref(q, kp, vp, table, jnp.asarray(lengths))
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=2e-3,
                                atol=2e-3)
+
+
+def _garbage_setup(rng, B, K, hd, pt, max_pages, lengths, quant):
+    """Pools and a page table where every K/V row the kernel must not read
+    is NaN (float pools) or has a NaN scale (int8 pools): rows past each
+    length in its last page, two reserved pages mapped past each length,
+    and page 0, which unmapped (-1) entries clamp to. Returns the clean
+    pools (garbage zeroed) for the oracle, then the planted ones."""
+    from repro.serve import kvquant
+    need = [-(-int(n) // pt) for n in lengths]
+    n_pages = 1 + sum(need) + 2 * B
+    clean = [rng.standard_normal((n_pages, K, pt, hd)).astype(np.float32)
+             for _ in range(2)]
+    table = np.full((B, max_pages), -1, np.int32)
+    ids = iter(rng.permutation(np.arange(1, n_pages)))
+    bad = np.zeros((n_pages, pt), bool)                   # (page, row)
+    bad[0] = True
+    for b in range(B):
+        n = need[b] + min(2, max_pages - need[b])     # live + reserved
+        table[b, :n] = [next(ids) for _ in range(n)]
+        for j in range(n):
+            rows = np.arange(pt) + j * pt >= lengths[b]
+            bad[table[b, j]] |= rows
+    for x in clean:
+        x[np.broadcast_to(bad[:, None, :, None], x.shape)] = 0.0
+    if not quant:
+        planted = [np.where(bad[:, None, :, None], np.nan, x) for x in clean]
+        return ([jnp.asarray(x) for x in clean],
+                [jnp.asarray(x) for x in planted], jnp.asarray(table))
+    qs = [kvquant.quantize_pages(jnp.asarray(x)) for x in clean]
+    dead = ~np.isin(np.arange(n_pages), [table[b, j] for b in range(B)
+                                         for j in range(need[b])])
+    planted = [(x, jnp.where(jnp.asarray(dead)[:, None], jnp.nan, s))
+               for x, s in qs]
+    return qs, planted, jnp.asarray(table)
+
+
+@pytest.mark.parametrize("case", [
+    # 129 pages of 16: the last block of 8 pages holds one (ragged)
+    dict(H=4, K=2, hd=32, pt=16, max_pages=129, lengths=[2064, 1000, 5]),
+    # inactive slots (length 0) beside full-length ones
+    dict(H=4, K=2, hd=32, pt=16, max_pages=24, lengths=[0, 384, 0, 384]),
+    # block edges: 8 pages of 16 make a block of 128 positions
+    dict(H=6, K=3, hd=32, pt=16, max_pages=24,
+         lengths=[127, 128, 129, 256, 1]),
+    # the int8 pool under the same walk
+    dict(H=4, K=2, hd=32, pt=16, max_pages=129, lengths=[0, 129, 2064],
+         quant=True),
+    # minitron's heads: K 8, G 3, head dim 128
+    dict(H=24, K=8, hd=128, pt=16, max_pages=40, lengths=[640, 129]),
+], ids=["ragged_129_pages", "inactive_slots", "block_edges", "int8",
+        "minitron_heads"])
+def test_paged_flash_decode_block_edges(case):
+    """Blocks of whole pages: ragged last blocks, lengths at and around a
+    block's edge, inactive slots (zeros out) — with every row the kernel
+    must not read planted as NaN, and the output finite and equal to the
+    oracle on the clean pools."""
+    from repro.kernels import paged_decode_attention as pda
+    quant = case.get("quant", False)
+    lengths = np.asarray(case["lengths"], np.int32)
+    B, H, K, hd, pt = len(lengths), case["H"], case["K"], case["hd"], case["pt"]
+    rng = np.random.default_rng(int(lengths.sum()))
+    q = jnp.asarray(rng.standard_normal((B, H, hd)).astype(np.float32))
+    clean, planted, table = _garbage_setup(rng, B, K, hd, pt,
+                                           case["max_pages"], lengths, quant)
+    lens = jnp.asarray(lengths)
+    if quant:
+        (kq, ks), (vq, vs) = planted
+        out = pda.paged_flash_decode(q, kq, vq, table, lens, k_scale=ks,
+                                     v_scale=vs, interpret=True)
+        (kq, ks), (vq, vs) = clean
+        exp = pda.paged_decode_attention_ref(q, kq, vq, table, lens,
+                                             k_scale=ks, v_scale=vs)
+    else:
+        out = pda.paged_flash_decode(q, *planted, table, lens,
+                                     interpret=True)
+        exp = pda.paged_decode_attention_ref(q, *clean, table, lens)
+    exp = jnp.where(lens[:, None, None] > 0, exp, 0.0)   # inactive: zeros
+    assert bool(jnp.isfinite(out).all())
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=2e-3,
+                               atol=2e-3)
+
+
+def test_walk_table_copies_only_live_pages():
+    """The walk's table changes a block column's page only where the new
+    page holds live positions, so the pipeline, which copies a column when
+    its page changes from one grid step to the next, copies nothing past a
+    slot's length — reserved pages, unmapped entries and the ragged last
+    block's missing columns included — beyond each column's first copy."""
+    from repro.kernels import paged_decode_attention as pda
+    rng = np.random.default_rng(5)
+    B, max_pages, pt, ppb, P = 6, 13, 16, 4, 96     # 13 = 3 blocks + 1
+    lengths = np.array([0, 100, 208, 17, 64, 0], np.int32)
+    table = np.full((B, max_pages), -1, np.int32)
+    ids = iter(rng.permutation(P))
+    for b in range(B):
+        n = min(max_pages, -(-int(lengths[b]) // pt) + 2)   # + reserved
+        table[b, :n] = [next(ids) for _ in range(n)]
+    walk = np.asarray(pda.walk_table(jnp.asarray(table), jnp.asarray(lengths),
+                                     P, pt, ppb))
+    assert walk.shape == table.shape and walk.min() >= 0 and walk.max() < P
+    held = [None] * ppb
+    copies = 0
+    for b in range(B):
+        for i in range(-(-max_pages // ppb)):
+            for j in range(ppb):
+                g = i * ppb + j
+                page = walk[b, g if g < max_pages else g - ppb]
+                live = g * pt < lengths[b]
+                if live:
+                    assert page == table[b, g]
+                if page != held[j]:
+                    assert live or held[j] is None, (b, i, j)
+                    copies += 1
+                held[j] = page
+    n_live = sum(-(-int(n) // pt) for n in lengths)
+    assert copies <= n_live + ppb
 
 
 def test_paged_matches_dense_flash_decode():

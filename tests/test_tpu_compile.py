@@ -2,7 +2,8 @@
 
 The paged attention kernels and the full-width paged steps of the served
 path are compiled here by the TPU compiler (Mosaic for the kernels) at
-qwen2-0.5b's published widths, with ``interpret=False``. Interpret-mode tests
+qwen2-0.5b's published widths, and the decode kernel also at the benchmark
+cell's minitron widths, with ``interpret=False``. Interpret-mode tests
 cannot see what this catches: blocks the TPU tiling refuses, kernels that
 need more fast memory than they may use, steps that do not fit the chip.
 Nothing runs, so nothing here is a result or a time.
@@ -88,6 +89,26 @@ def test_paged_kernel_compiles(one_chip, cfg, kernel, kv_dtype, tp):
     compiled = _compile(lambda *a: fn(*a, interpret=False), *args)
     # the kernel keeps its name on the chip, so a profile can tell it apart
     assert f"%paged_{kernel}_attention" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8])
+def test_decode_kernel_compiles_at_cell_shapes(one_chip, kv_dtype):
+    """The decode kernel at the benchmark cell's shapes (minitron widths: 16
+    slots, 8 kv heads of GQA 3 at head dim 128, a 129-page table of 16-token
+    pages). Its first operand stays the 2-D page table: the trace reduction
+    tells the decode kernel from the prefill kernel by that operand's rank."""
+    B_, H, K, hd, pages = 16, 24, 8, 128, 129
+    pool = _sds((1024, K, PT, hd), kv_dtype, one_chip)
+    args = [_sds((B_, H, hd), jnp.float32, one_chip), pool, pool,
+            _sds((B_, pages), jnp.int32, one_chip),
+            _sds((B_,), jnp.int32, one_chip)]
+    if kv_dtype == jnp.int8:
+        args += [_sds((1024, K), jnp.float32, one_chip)] * 2
+    text = _compile(lambda *a: paged_flash_decode(*a, interpret=False),
+                    *args).as_text()
+    call = next(ln for ln in text.splitlines()
+                if "%paged_decode_attention" in ln and "custom-call(" in ln)
+    assert "operand_layout_constraints={s32[16,129]{" in call
 
 
 @pytest.mark.parametrize("step", ["decode", "chunk"])
