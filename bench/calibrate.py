@@ -64,9 +64,9 @@ def _sweep(h, spec, args, say):
 
 
 def _control(h, spec, args, say):
-    from reference import check, forward
+    from reference import check
     counter = h.CompileCounter()
-    dims = forward.Dims.of(spec.conf)
+    dims = spec.ref.Dims.of(spec.conf)
     length = spec.cell["engine"]["max_seq"]
     for seed in [int(s) for s in args.seeds.split(",")]:
         cfg, params, econf = h.setup(spec, seed, say, T_PROCESS)
@@ -75,8 +75,9 @@ def _control(h, spec, args, say):
         pick = h.sample(win.records, seed, spec.cell["check"]["requests"])
         samples = [{"prompt": r.req.prompt.tolist(),
                     "served": list(r.req.tokens_out)} for r in pick]
-        prog = check.served_gaps(params, dims, length, samples)
-        ctrl = check.served_gaps(params, dims, length, samples, quant="fp8")
+        prog = check.served_gaps(params, spec.ref, dims, length, samples)
+        ctrl = check.served_gaps(params, spec.ref, dims, length, samples,
+                                 quant="fp8")
         flat = lambda gs: [float(x) for g in gs for x in g]
         p, c = flat(prog), flat(ctrl)
         print(json.dumps({"seed": seed, "tokens": len(p),

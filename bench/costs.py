@@ -1,5 +1,10 @@
 """Operations and bytes that the algorithm needs for the tokens served.
 
+The block the configuration names gives the matrix products of its layers
+(``bench/blocks/<block>.py``, ``layer_flops``); this module adds the
+attention over the context, the output head and the K/V bytes, which follow
+from the head counts alone.
+
 The count is of the work the tokens need, whatever computes it: it does not
 follow the kernels' page walks, padding or chunking, so a faster
 implementation shows as a higher share of the roofline and no implementation
@@ -18,7 +23,7 @@ can read above 100%. A matrix product [m, k] x [k, n] is 2mkn operations
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Iterable, Tuple
+from typing import Dict, Iterable, Sequence, Tuple
 
 KV_BYTES = 2        # bf16 pages
 QO_BYTES = 4        # float32 q in, float32 out of the attention kernels
@@ -30,26 +35,19 @@ class Shape:
     n_heads: int
     n_kv: int
     hd: int
-    ff: int
     vocab: int
     layers: int
-    mlp_mats: int       # 3 for a gated (silu) MLP, 2 for relu2
+    matmul_flops: int   # every layer's matrix products, for one token
 
     @classmethod
-    def of(cls, conf: Dict) -> "Shape":
+    def of(cls, conf: Dict, layer_flops: Sequence[int]) -> "Shape":
+        """``layer_flops``: the block's matrix-product FLOPs one token needs
+        in each layer (``bench/blocks/<block>.py``), every layer attending."""
         d, H = conf["hidden_size"], conf["num_attention_heads"]
         return cls(d=d, n_heads=H, n_kv=conf["num_key_value_heads"],
                    hd=conf.get("head_dim") or d // H,
-                   ff=conf["intermediate_size"], vocab=conf["vocab_size"],
-                   layers=conf["num_hidden_layers"],
-                   mlp_mats=3 if conf["hidden_act"] == "silu" else 2)
-
-
-def layer_matmul_flops(s: Shape) -> int:
-    """Projections and MLP of one layer, for one token."""
-    qo = 2 * 2 * s.d * s.n_heads * s.hd
-    kv = 2 * 2 * s.d * s.n_kv * s.hd
-    return qo + kv + 2 * s.mlp_mats * s.d * s.ff
+                   vocab=conf["vocab_size"], layers=len(layer_flops),
+                   matmul_flops=sum(layer_flops))
 
 
 def head_flops(s: Shape) -> int:
@@ -100,7 +98,7 @@ def count(s: Shape, decode_contexts: Iterable[int],
     """Work of the decode tokens (their contexts), the prefill chunks
     ((start, size) each) and the ``emitted`` tokens' output head."""
     w = Work()
-    per_tok = s.layers * layer_matmul_flops(s)
+    per_tok = s.matmul_flops
     for c in decode_contexts:
         w.decode_tokens += 1
         w.decode_attn_flops += attn_flops(s, c)

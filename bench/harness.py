@@ -5,7 +5,12 @@ a file of its own, found by name:
 
   BENCHMARK.json                the cells, the metrics, their bounds
   bench/workloads/<cell>.json   configuration, mix, load, engine, check
-  bench/configs/<config>.json   the model's published config as run
+  bench/configs/<config>.json   the model's published config as run; its
+                                ``block`` names the two files below
+  bench/blocks/<block>.py       the block as the program runs it: its
+                                ``program_config``, weight ``layout`` and
+                                ``layer_flops``
+  bench/reference/<block>.py    the block's float32 reference
   bench/traffic/<mix>.json      the mix's parameters (traffic/generator.py)
   bench/metrics/<name>.py       an end-to-end metric's reader
   bench/layer_metrics/<name>.py a per-layer metric's reader
@@ -33,6 +38,7 @@ import math
 import pathlib
 import sys
 import time
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -41,7 +47,7 @@ import numpy as np
 
 import model
 import trace_reduce
-from reference import check, forward
+from reference import check
 from traffic import generator
 
 from repro.serve.cache import CacheConfig
@@ -67,11 +73,37 @@ class Spec:
     end_to_end: List[Dict]
     per_layer: List[Dict]
     peaks: Dict
+    block: ModuleType           # bench/blocks/<block>.py
+    ref: ModuleType             # bench/reference/<block>.py
     chips: int = 1
 
 
 def _applies(metric: Dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
+
+
+def _module(path: pathlib.Path, name: str) -> ModuleType:
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    sys.modules[name] = mod         # dataclasses look their module up
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
+def block_of(conf_path: pathlib.Path, conf: Dict) -> tuple:
+    """The modules of the block the configuration file names: its program
+    side and its reference, beside the file under ``bench/``."""
+    if "block" not in conf:
+        raise ValueError(f"{conf_path}: names no \"block\"")
+    bench = conf_path.parent.parent
+    mods = []
+    for kind in ("blocks", "reference"):
+        path = bench / kind / f"{conf['block']}.py"
+        if not path.is_file():
+            raise ValueError(f"{conf_path}: its block {conf['block']!r} has "
+                             f"no {kind}/{path.name} in {bench}")
+        mods.append(_module(path, f"bench_{kind}_{conf['block']}"))
+    return tuple(mods)
 
 
 def spec(name: str, root: pathlib.Path = ROOT) -> Spec:
@@ -82,13 +114,16 @@ def spec(name: str, root: pathlib.Path = ROOT) -> Spec:
                                              entry["traffic"]):
         raise ValueError(f"{name}: its file and BENCHMARK.json disagree on "
                          "configuration or traffic")
+    conf_path = root / "bench" / "configs" / f"{cell['config']}.json"
+    conf = load(conf_path)
+    block, ref = block_of(conf_path, conf)
     return Spec(
-        cell=cell,
-        conf=load(root / "bench" / "configs" / f"{cell['config']}.json"),
+        cell=cell, conf=conf,
         mix=load(root / "bench" / "traffic" / f"{cell['traffic']}.json"),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, name)],
-        peaks=load(root / "bench" / "peaks.json"), chips=entry["chips"])
+        peaks=load(root / "bench" / "peaks.json"), block=block, ref=ref,
+        chips=entry["chips"])
 
 
 class CompileCounter:
@@ -148,6 +183,7 @@ class Window:
     trace_t0: float = 0.0
     trace_t1: float = 0.0
     device_kind: str = ""
+    block: Optional[ModuleType] = None     # for the work count
 
     def peak(self) -> Dict:
         """The device kind's peaks; a kind not in peaks.json is an error."""
@@ -199,8 +235,8 @@ class Window:
         chunks = [(start, size) for tb, te, log in self.steps
                   if t0 <= tb < t1 for e in log
                   for _, start, size in e["chunks"]]
-        return costs.count(costs.Shape.of(self.conf), contexts, chunks,
-                           self.emitted(t0, t1))
+        shape = costs.Shape.of(self.conf, self.block.layer_flops(self.conf))
+        return costs.count(shape, contexts, chunks, self.emitted(t0, t1))
 
 
 def quantile(values: List[float], p: float) -> Optional[float]:
@@ -211,12 +247,8 @@ def quantile(values: List[float], p: float) -> Optional[float]:
 
 
 def _reader(kind: str, name: str) -> Callable:
-    path = BENCH / kind / f"{name}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        f"bench_{kind}_{name}".replace("-", "_").replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return _module(BENCH / kind / f"{name}.py", f"bench_{kind}_{name}"
+                   .replace("-", "_").replace(".", "_")).read
 
 
 def engine_config(cell: Dict) -> EngineConfig:
@@ -414,9 +446,8 @@ def correctness(spec_: Spec, params, records: List[Record], seed: int,
         return {"tokens": 0, "requests": 0, "worst_gap_sigma": math.inf}
     samples = [{"prompt": r.req.prompt.tolist(),
                 "served": list(r.req.tokens_out)} for r in pick]
-    dims = forward.Dims.of(spec_.conf)
-    gaps = check.served_gaps(params, dims, spec_.cell["engine"]["max_seq"],
-                             samples)
+    gaps = check.served_gaps(params, spec_.ref, spec_.ref.Dims.of(spec_.conf),
+                             spec_.cell["engine"]["max_seq"], samples)
     allg = np.concatenate(gaps)
     return {"tokens": int(allg.size), "requests": len(pick),
             "worst_gap_sigma": float(allg.max()) if allg.size else math.inf}
@@ -426,9 +457,9 @@ def setup(spec_: Spec, seed: int, say, t_process: float) -> tuple:
     """The program's config, the weights from ``seed`` and the engine
     config, with every program the window can dispatch warmed up."""
     cell, conf = spec_.cell, spec_.conf
-    cfg = model.program_config(conf)
+    cfg = spec_.block.program_config(conf)
     generator.validate(spec_.mix, cell["engine"]["max_seq"])
-    params = model.make_params(conf, seed)
+    params = model.make_params(spec_.block.layout(conf), seed)
     jax.block_until_ready(params)
     say(f"weights made at {CLOCK() - t_process:.3f} s")
     econf = engine_config(cell)
@@ -491,7 +522,7 @@ def measure(spec_: Spec, cfg, params, econf, seed: int, seconds: float,
                  setup_programs=setup_programs,
                  window_programs=counter.programs - setup_programs,
                  steps=drv.steps, trace_t0=trace_t0, trace_t1=trace_t1,
-                 device_kind=dev.device_kind)
+                 device_kind=dev.device_kind, block=spec_.block)
     failed = stats1.get("rejected", 0) + drv.refused
     del eng, drv, serve     # the engine and its pool, before any reference
     gc.collect()

@@ -6,7 +6,7 @@ import costs
 
 
 def read(w):
-    t = w.trace.kernel_s.get("decode") if w.trace else None
+    t = w.trace.kernel_s.get("paged_decode_attention") if w.trace else None
     if not t:
         return None
     work = w.work(w.trace_t0, w.trace_t1)

@@ -5,7 +5,7 @@ import costs
 
 
 def read(w):
-    t = w.trace.kernel_s.get("prefill") if w.trace else None
+    t = w.trace.kernel_s.get("paged_prefill_attention") if w.trace else None
     if not t:
         return None
     work = w.work(w.trace_t0, w.trace_t1)

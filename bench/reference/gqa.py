@@ -1,9 +1,10 @@
-"""Plain float32 forward of a decoder-only GQA transformer, from its config.
+"""Plain float32 forward of the dense GQA decoder block, from its config.
 
-Straight ``jax.numpy`` with every matrix product at ``Precision.HIGHEST``:
-no kernels, no cache, no paging, no batching. It reads the architecture from
-the configuration file (Hugging Face keys) and the weights from the pytree
-the benchmark made from the seed (``bench/model.py``), never from the program.
+Straight ``jax.numpy`` with every matrix product at ``Precision.HIGHEST``
+(``common.py``): no kernels, no cache, no paging, no batching. It reads the
+architecture from the configuration file (Hugging Face keys) and the weights
+from the pytree the benchmark made from the seed (``bench/model.py``, laid
+out by ``bench/blocks/gqa.py``), never from the program.
 
 The block, as the configuration states it:
 
@@ -16,9 +17,7 @@ The block, as the configuration states it:
                 or relu(h Wi)^2 Wo            (hidden_act "relu2")
   logits = (rms(x) * final) head,  head = embed^T when tied
 
-``quant`` names a lower precision for the control (``"fp8"``): each operand
-of every matrix product is rounded to float8 e4m3 with a per-tensor scale
-(amax / 448) before the product, as an fp8 serving path would store it.
+``quant`` names the control's lower precision (``common.mm``).
 """
 from __future__ import annotations
 
@@ -28,8 +27,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-HIGHEST = jax.lax.Precision.HIGHEST
-FP8_MAX = 448.0
+from reference.common import mm as _mm, rms as _rms, rope as _rope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,33 +56,6 @@ class Dims:
                    theta=float(conf["rope_theta"]), act=conf["hidden_act"],
                    bias=bool(conf.get("attention_bias", False)),
                    tied=bool(conf["tie_word_embeddings"]))
-
-
-def _fp8(a):
-    s = jnp.maximum(jnp.max(jnp.abs(a)), 1e-30) / FP8_MAX
-    return (a / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
-
-
-def _mm(quant: Optional[str]):
-    if quant is None:
-        return lambda a, b: jnp.matmul(a, b, precision=HIGHEST)
-    if quant == "fp8":
-        return lambda a, b: jnp.matmul(_fp8(a), _fp8(b), precision=HIGHEST)
-    raise ValueError(f"unknown control precision {quant!r}")
-
-
-def _rms(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
-
-
-def _rope(x, theta):
-    """x: [L, heads, hd]; rotate the two halves by position."""
-    L, _, hd = x.shape
-    inv = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    ang = jnp.arange(L, dtype=jnp.float32)[:, None] * inv       # [L, hd/2]
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
 def hidden(params, tokens, dims: Dims, quant: Optional[str] = None):

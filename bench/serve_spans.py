@@ -22,10 +22,11 @@ the profiler syncs the two clocks, and in two traces of six on one TPU v5e
 it placed the device's events about 1.4 ms early, which moved that much of
 each iteration from dispatch and the scheduler into the fetch.
 
-The readers find the trace where ``run.py`` keeps it while they read,
-``.bench_cache/trace/<cell>/``, and read it only if it was written after
-the window's trace began; a trace kept anywhere else they do not see. To
-read a trace or a recorded fixture, with each long gap labelled:
+``trace_reduce.reduce`` makes the split once, with the rest of the
+reduction, and labels each long gap of the ``breakdown`` by the serve span
+covering most of it (``label_gaps``); the readers read the split of the
+window's own trace (``of``). To read a trace or a recorded fixture, with
+each long gap labelled:
 
     python3 bench/serve_spans.py <file.xplane.pb | events.json.gz>
 """
@@ -34,20 +35,15 @@ from __future__ import annotations
 import bisect
 import collections
 import dataclasses
-import functools
 import gzip
 import json
 import math
-import os
-import pathlib
 import statistics
 import sys
-import time
 from typing import Dict, List, Optional, Tuple
 
 import trace_reduce
 
-TRACES = pathlib.Path(__file__).resolve().parents[1] / ".bench_cache/trace"
 PARTS = ("sched", "dispatch", "fetch", "outside")
 SCHED = ("iteration", "schedule", "policy", "submit", "commit",
          "decode_inputs", "prefill_chunk", "cow_copy", "swap_wait")
@@ -62,43 +58,6 @@ DECODE_PROGRAM = "jit_decode_step"
 # or more for a program that found the device idle)
 QUEUED_NS = 100e3
 Span = trace_reduce.Span
-
-
-def extract(path: str) -> Dict:
-    """``trace_reduce.extract``'s events, plus the host's serve spans under
-    ``serve``."""
-    from jax.profiler import ProfileData
-    events = trace_reduce.extract(path)
-    serve = []
-    for plane in ProfileData.from_file(path).planes:
-        if plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for ev in line.events:
-                    if ev.name.startswith("serve."):
-                        serve.append((ev.name, float(ev.start_ns),
-                                      float(ev.start_ns + ev.duration_ns)))
-    events["serve"] = serve
-    return events
-
-
-def _window(events: Dict, devs: List[Dict]) -> Tuple[float, float]:
-    windows = [(s, e) for n, s, e in events["host"] if n == "bench.window"]
-    if windows:
-        return min(s for s, _ in windows), max(e for _, e in windows)
-    allev = [x for d in devs for x in d["modules"] + d["ops"]]
-    return min(x[1] for x in allev), max(x[2] for x in allev)
-
-
-def _idle(dev: Dict, w0: float, w1: float, shift: float = 0.0
-          ) -> List[Tuple[float, float]]:
-    """One device's idle intervals in the window, as ``reduce`` finds them,
-    its times moved by ``shift``."""
-    busy = trace_reduce._union(trace_reduce._clip(
-        [(s + shift, e + shift) for _, s, e in dev["modules"] + dev["ops"]],
-        w0, w1))
-    edges = [w0] + [x for s, e in busy for x in (s, e)] + [w1]
-    return [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
-            if edges[i + 1] > edges[i]]
 
 
 def innermost(spans: List[Span]) -> List[Span]:
@@ -216,7 +175,7 @@ def split(events: Dict) -> Optional[Split]:
     serve = events.get("serve", [])
     if not devs or not serve:
         return None
-    w0, w1 = _window(events, devs)
+    w0, w1 = trace_reduce.window(events, devs)
     iters = [(s, e) for n, s, e in serve
              if n == "serve.iteration" and w0 <= s < w1]
     if not iters:
@@ -227,7 +186,7 @@ def split(events: Dict) -> Optional[Split]:
         if shift is None:
             return None
         shifts.append(shift / 1e9)
-        idle += _idle(d, w0, w1, shift)
+        idle += trace_reduce.idle(trace_reduce.busy(d, w0, w1, shift), w0, w1)
     pieces = innermost(serve)
     starts = [p[1] for p in pieces]
     idle_ns = dict.fromkeys(PARTS, 0.0)
@@ -250,17 +209,17 @@ def split(events: Dict) -> Optional[Split]:
                  shift_s=shifts, idle=idle)
 
 
-def label_gaps(events: Dict, s: Split, n: int = 10
+def label_gaps(events: Dict, idle: List[Tuple[float, float]], n: int = 10
                ) -> List[Tuple[str, float]]:
-    """The ``n`` longest idle gaps, each labelled by the serve span covering
-    most of it, else by the harness span covering most of it (as
-    ``trace_reduce`` labels them), else ``outside``."""
-    pieces = innermost(events["serve"])
+    """The ``n`` longest of the ``idle`` intervals, each labelled by the
+    innermost serve span covering most of it, else by the harness span
+    covering most of it, else ``outside``."""
+    pieces = innermost(events.get("serve", []))
     starts = [p[1] for p in pieces]
     harness = innermost([x for x in events["host"] if x[0] != "bench.window"])
     h_starts = [p[1] for p in harness]
     gaps = []
-    for a, b in sorted(s.idle, key=lambda g: g[0] - g[1])[:n]:
+    for a, b in sorted(idle, key=lambda g: g[0] - g[1])[:n]:
         got = (_cover(pieces, starts, a, b)
                or _cover(harness, h_starts, a, b))
         gaps.append((max(got, key=got.get) if got else "outside",
@@ -268,25 +227,9 @@ def label_gaps(events: Dict, s: Split, n: int = 10
     return gaps
 
 
-@functools.lru_cache(maxsize=1)
-def _split_file(path: str, mtime: float) -> Optional[Split]:
-    return split(extract(path))
-
-
 def of(w) -> Optional[Split]:
-    """The split of a traced window's trace, read once for all readers;
-    None where ``run.py``'s trace of the cell is missing or older than the
-    window's trace."""
-    from harness import CLOCK
-    if w.trace is None:
-        return None
-    path = trace_reduce.find_trace(TRACES / w.name)
-    if path is None:
-        return None
-    mtime = os.path.getmtime(path)
-    if mtime < time.time() - (CLOCK() - w.trace_t0):
-        return None
-    return _split_file(path, mtime)
+    """The split of a traced window's trace; None without one."""
+    return w.trace.split if w.trace is not None else None
 
 
 def main(argv: List[str]) -> int:
@@ -295,7 +238,7 @@ def main(argv: List[str]) -> int:
         with gzip.open(path, "rt") as f:
             events = json.load(f)
     else:
-        events = extract(path)
+        events = trace_reduce.extract(path)
     s = split(events)
     if s is None:
         print("no serve spans and device ops in this trace, or clocks that "
@@ -306,7 +249,7 @@ def main(argv: List[str]) -> int:
         "clock_shift_ms": [1000.0 * x for x in s.shift_s],
         "idle_ms_per_iteration": {p: s.idle_ms(p) for p in PARTS},
         "host_step_ms": s.host_step_ms(),
-        "idle_gaps": label_gaps(events, s)}, indent=1))
+        "idle_gaps": label_gaps(events, s.idle)}, indent=1))
     return 0
 
 

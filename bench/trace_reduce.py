@@ -5,17 +5,15 @@ has one plane per chip (``/device:TPU:<n>``) with an ``XLA Modules`` line
 (one event per program execution, named ``jit_<function>(<fingerprint>)``)
 and an ``XLA Ops`` line (one event per HLO op, named by its HLO text). The
 host plane (``/host:CPU``) holds the benchmark's own
-``jax.profiler.TraceAnnotation`` spans on the same clock.
+``jax.profiler.TraceAnnotation`` spans and the program's ``serve.*`` spans
+(``serve_spans.py``) on the same clock.
 
-How the trace names the served path today (TPU v5e, JAX 0.9): the decode
-step and every prefill-chunk step are all ``jit_sampled(...)``, and both
-Pallas attention kernels are ``%closed_call.N = ... custom-call(...),
-custom_call_target="tpu_custom_call"`` with empty kernel metadata. Neither
-carries a name of its own, so the reduction tells them apart by shape: the
-decode kernel's first operand is the batch's page table, ``s32[B, pages]``;
-the prefill kernel's is one sequence's row, ``s32[pages]``. A program is a
-decode program if it runs the decode kernel, a prefill program if it runs
-the prefill kernel.
+A Pallas kernel is an op ``%<name>.<n> = ... custom-call(...),
+custom_call_target="tpu_custom_call"``, named by the ``name`` the program
+gives it (``%paged_decode_attention.7``). Device time and calls are kept per
+kernel name, so a reader of a new kernel's roofline needs no change here. A
+program is a decode program if it runs the paged decode attention kernel, a
+prefill program if it runs the paged prefill one (``PROGRAM_KIND``).
 
 ``extract`` turns a trace into plain lists of (name, start, end) events,
 which is also the form of the recorded fixture in ``tests/bench``;
@@ -32,7 +30,9 @@ import re
 from typing import Dict, List, Optional, Tuple
 
 KERNEL = 'custom_call_target="tpu_custom_call"'
-_FIRST_OPERAND = re.compile(r"custom-call\(s32\[([\d,]*)\]")
+_NUMBERED = re.compile(r"\.\d+$")
+PROGRAM_KIND = {"paged_decode_attention": "decode",
+                "paged_prefill_attention": "prefill"}
 HOST_SPANS = ("bench.window", "wait_arrival", "Engine.submit",
               "Engine.step")
 Span = Tuple[str, float, float]        # name, start ns, end ns
@@ -40,10 +40,10 @@ Span = Tuple[str, float, float]        # name, start ns, end ns
 
 def extract(path: str) -> Dict:
     """Events of an ``.xplane.pb`` trace: per device its modules and ops,
-    and the benchmark's host spans."""
+    the benchmark's host spans, and the program's ``serve.*`` spans."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
-    devices, host = [], []
+    devices, host, serve = [], [], []
     for plane in pd.planes:
         if plane.name.startswith("/device:") and "CUSTOM" not in plane.name:
             lines = {ln.name: ln for ln in plane.lines}
@@ -58,10 +58,12 @@ def extract(path: str) -> Dict:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name in HOST_SPANS:
-                        host.append((ev.name, float(ev.start_ns),
+                    into = (host if ev.name in HOST_SPANS else serve
+                            if ev.name.startswith("serve.") else None)
+                    if into is not None:
+                        into.append((ev.name, float(ev.start_ns),
                                      float(ev.start_ns + ev.duration_ns)))
-    return {"devices": devices, "host": host}
+    return {"devices": devices, "host": host, "serve": serve}
 
 
 def find_trace(trace_dir: str) -> Optional[str]:
@@ -70,15 +72,12 @@ def find_trace(trace_dir: str) -> Optional[str]:
     return found[-1] if found else None
 
 
-def kernel_kind(op_name: str) -> Optional[str]:
-    """``decode`` / ``prefill`` for the two paged attention kernels."""
+def kernel_name(op_name: str) -> Optional[str]:
+    """The name of a Pallas kernel's op (``%paged_decode_attention.7 = ...``
+    -> ``paged_decode_attention``); None for any other op."""
     if KERNEL not in op_name:
         return None
-    m = _FIRST_OPERAND.search(op_name)
-    if m is None:
-        return "other"
-    rank = len([d for d in m.group(1).split(",") if d])
-    return {2: "decode", 1: "prefill"}.get(rank, "other")
+    return _NUMBERED.sub("", op_name.split(" = ")[0].lstrip("%"))
 
 
 def _union(spans: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
@@ -95,16 +94,42 @@ def _clip(spans, lo, hi):
     return [(max(s, lo), min(e, hi)) for s, e in spans if e > lo and s < hi]
 
 
+def window(events: Dict, devs: List[Dict]) -> Tuple[float, float]:
+    """The ``bench.window`` span, else the span of the devices' events."""
+    windows = [(s, e) for n, s, e in events["host"] if n == "bench.window"]
+    if windows:
+        return min(s for s, _ in windows), max(e for _, e in windows)
+    allev = [x for d in devs for x in d["modules"] + d["ops"]]
+    return min(x[1] for x in allev), max(x[2] for x in allev)
+
+
+def busy(dev: Dict, w0: float, w1: float, shift: float = 0.0
+         ) -> List[Tuple[float, float]]:
+    """The union of one device's events in the window, its times moved by
+    ``shift``."""
+    return _union(_clip([(s + shift, e + shift) for _, s, e in
+                         dev["modules"] + dev["ops"]], w0, w1))
+
+
+def idle(busy_: List[Tuple[float, float]], w0: float, w1: float
+         ) -> List[Tuple[float, float]]:
+    """The window's intervals outside a busy union."""
+    edges = [w0] + [x for s, e in busy_ for x in (s, e)] + [w1]
+    return [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
+            if edges[i + 1] > edges[i]]
+
+
 @dataclasses.dataclass
 class Reduction:
     window_s: float
     busy_s: float                       # averaged over the devices
     decode_steps_s: List[float]         # each decode program execution
     prefill_programs_s: float           # all prefill program executions
-    kernel_s: Dict[str, float]          # summed device time per kernel kind
+    kernel_s: Dict[str, float]          # summed device time per kernel name
     kernel_calls: Dict[str, int]
     op_self_s: Dict[str, float]         # self time per op, by label
     gaps: List[Tuple[str, float]]       # idle gaps, labelled, longest first
+    split: Optional["serve_spans.Split"] = None   # idle by serve span
 
     def breakdown(self) -> Dict:
         ops = sorted(self.op_self_s.items(), key=lambda kv: -kv[1])[:10]
@@ -123,20 +148,14 @@ def reduce(events: Dict) -> Optional[Reduction]:
     devs = [d for d in events["devices"] if d["modules"] or d["ops"]]
     if not devs:
         return None
-    host = events["host"]
-    windows = [(s, e) for n, s, e in host if n == "bench.window"]
-    if windows:
-        w0, w1 = min(s for s, _ in windows), max(e for _, e in windows)
-    else:
-        allev = [x for d in devs for x in d["modules"] + d["ops"]]
-        w0, w1 = min(x[1] for x in allev), max(x[2] for x in allev)
+    w0, w1 = window(events, devs)
     busy_total = 0.0
     decode_steps: List[float] = []
     prefill_s = 0.0
     kernel_s: Dict[str, float] = collections.Counter()
     kernel_calls: Dict[str, int] = collections.Counter()
     op_self: Dict[str, float] = collections.Counter()
-    idle: List[Tuple[float, float]] = []
+    gaps: List[Tuple[float, float]] = []
     for d in devs:
         mods = sorted(d["modules"], key=lambda x: x[1])
         ops = sorted(d["ops"], key=lambda x: (x[1], -x[2]))
@@ -148,11 +167,11 @@ def reduce(events: Dict) -> Optional[Reduction]:
 
         kinds: Dict[str, str] = {}
         for name, s, e in ops:
-            k = kernel_kind(name)
-            if k in ("decode", "prefill"):
+            k = PROGRAM_KIND.get(kernel_name(name))
+            if k:
                 kinds.setdefault(module_of(s), k)
         for name, s, e in ops:
-            k = kernel_kind(name)
+            k = kernel_name(name)
             if k and w0 <= s < w1:
                 kernel_s[k] += (min(e, w1) - s) / 1e9
                 kernel_calls[k] += 1
@@ -174,25 +193,20 @@ def reduce(events: Dict) -> Optional[Reduction]:
                                  kinds.get(mod, "other")), s, e, e - s])
         while stack:
             _pop(stack, op_self)
-        busy = _union(_clip([(s, e) for _, s, e in mods + ops], w0, w1))
-        busy_total += sum(e - s for s, e in busy) / 1e9
-        edges = [w0] + [x for s, e in busy for x in (s, e)] + [w1]
-        idle += [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
-                 if edges[i + 1] > edges[i]]
-    gaps = []
-    spans = [(n, s, e) for n, s, e in host if n != "bench.window"]
-    for s, e in sorted(idle, key=lambda g: g[0] - g[1])[:10]:
-        best, cover = "no span", 0.0
-        for n, hs, he in spans:
-            c = min(e, he) - max(s, hs)
-            if c > cover:
-                best, cover = n, c
-        gaps.append((best, (e - s) / 1e9))
+        union = busy(d, w0, w1)
+        busy_total += sum(e - s for s, e in union) / 1e9
+        gaps += idle(union, w0, w1)
+    # labelled by the program's serve spans on the host's clock where it has
+    # them, else by the harness's spans on the device's
+    import serve_spans      # it builds on this module's window and busy
+    split = serve_spans.split(events)
+    labelled = serve_spans.label_gaps(events, split.idle if split else gaps)
     return Reduction(
         window_s=(w1 - w0) / 1e9, busy_s=busy_total / len(devs),
         decode_steps_s=decode_steps, prefill_programs_s=prefill_s,
         kernel_s=dict(kernel_s), kernel_calls=dict(kernel_calls),
-        op_self_s={k: v / 1e9 for k, v in op_self.items()}, gaps=gaps)
+        op_self_s={k: v / 1e9 for k, v in op_self.items()}, gaps=labelled,
+        split=split)
 
 
 def _clip_named(ops, w0, w1):

@@ -39,6 +39,11 @@ def conf(name="qwen2-0.5b"):
     return c
 
 
+def block(name="qwen2-0.5b"):
+    """The configuration's block: its program side and its reference."""
+    return harness.block_of(BENCH / "configs" / f"{name}.json", conf(name))
+
+
 def cell(kind="chat"):
     if kind == "chat":
         # an open-loop cell with the prefix cache: the harness's other path
@@ -63,8 +68,10 @@ def spec(kind="chat", config="qwen2-0.5b") -> "harness.Spec":
     ok = lambda m: "workloads" not in m or c["name"] in m["workloads"]
     peaks = harness.load(BENCH / "peaks.json")
     peaks["devices"]["cpu"] = copy.deepcopy(peaks["devices"]["TPU v5 lite"])
+    program, ref = block(config)
     return harness.Spec(
         cell=c, conf=conf(config),
         mix=copy.deepcopy(CHAT_MIX if kind == "chat" else BATCH_MIX),
         end_to_end=[m for m in bench["end_to_end"] if ok(m)],
-        per_layer=[m for m in bench["per_layer"] if ok(m)], peaks=peaks)
+        per_layer=[m for m in bench["per_layer"] if ok(m)], peaks=peaks,
+        block=program, ref=ref)

@@ -16,7 +16,7 @@ import bench_smoke
 from bench_smoke import harness
 
 import model  # noqa: E402  (bench/, on the path through bench_smoke)
-from reference import check, forward
+from reference import check, common
 from repro.serve import executor, paged_step
 
 LIMIT = harness.load(bench_smoke.BENCH / "workloads" /
@@ -33,15 +33,15 @@ def fresh_programs(monkeypatch):
     jax.clear_caches()
 
 
-def _greedy(params, dims, prompt, n, length):
+def _greedy(ref, params, dims, prompt, n, length):
     """The reference put in the program's place: greedy tokens, one padded
     forward per token (causal, so the padding is never seen)."""
     @jax.jit
     def step(p, seq, pos):
-        h = forward.hidden(p, seq, dims)
+        h = ref.hidden(p, seq, dims)
         row = jax.lax.dynamic_index_in_dim(h, pos, keepdims=False)
-        return jnp.argmax(jnp.matmul(row, forward.head(p, dims),
-                                     precision=forward.HIGHEST))
+        return jnp.argmax(jnp.matmul(row, ref.head(p, dims),
+                                     precision=common.HIGHEST))
     seq = np.zeros(length, np.int32)
     seq[:len(prompt)] = prompt
     for i in range(n):
@@ -57,19 +57,22 @@ def test_control_in_fp8_fails_where_float32_reads_zero(config):
                 num_hidden_layers=4)
     if conf.get("head_dim"):
         conf["head_dim"] = 64
-    params = model.make_params(conf, 3)
-    dims = forward.Dims.of(conf)
+    block, ref = bench_smoke.block(config)
+    params = model.make_params(block.layout(conf), 3)
+    dims = ref.Dims.of(conf)
     rng = np.random.default_rng(0)
     length = 96
     samples = []
     for _ in range(3):
         prompt = rng.integers(0, dims.vocab, 48).tolist()
         samples.append({"prompt": prompt,
-                        "served": _greedy(params, dims, prompt, 24, length)})
-    ref = np.concatenate(check.served_gaps(params, dims, length, samples))
-    ctrl = np.concatenate(check.served_gaps(params, dims, length, samples,
-                                            quant="fp8"))
-    assert ref.max() == 0.0
+                        "served": _greedy(ref, params, dims, prompt, 24,
+                                          length)})
+    f32 = np.concatenate(check.served_gaps(params, ref, dims, length,
+                                           samples))
+    ctrl = np.concatenate(check.served_gaps(params, ref, dims, length,
+                                            samples, quant="fp8"))
+    assert f32.max() == 0.0
     assert ctrl.max() > LIMIT
 
 

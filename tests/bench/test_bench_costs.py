@@ -9,18 +9,23 @@ BENCH = pathlib.Path(__file__).resolve().parents[2] / "bench"
 sys.path.insert(0, str(BENCH))
 
 import costs  # noqa: E402
+from blocks import gqa  # noqa: E402
+
+
+def conf(name):
+    return json.loads((BENCH / "configs" / f"{name}.json").read_text())
 
 
 def shape(name):
-    return costs.Shape.of(json.loads(
-        (BENCH / "configs" / f"{name}.json").read_text()))
+    return costs.Shape.of(conf(name), gqa.layer_flops(conf(name)))
 
 
 def test_qwen2_per_token_counts():
     s = shape("qwen2-0.5b")
     # q and o: 2*2*896*896; k and v: 2*2*896*128; gated MLP: 3*2*896*4864
-    assert costs.layer_matmul_flops(s) == 3_211_264 + 458_752 + 26_148_864
-    assert 24 * costs.layer_matmul_flops(s) == 715_653_120
+    assert gqa.layer_flops(conf("qwen2-0.5b")) == \
+        [3_211_264 + 458_752 + 26_148_864] * 24
+    assert s.layers == 24 and s.matmul_flops == 715_653_120
     assert costs.head_flops(s) == 2 * 896 * 151_936 == 272_269_312
     assert costs.attn_flops(s, 1000) == 24 * 4 * 14 * 64 * 1000
     # live K/V is 12 KiB per token (2 kv heads x 64 x bf16 x k,v x 24)
@@ -32,8 +37,8 @@ def test_qwen2_per_token_counts():
 def test_minitron_per_token_counts():
     s = shape("minitron-4b-8l")
     # q, o: 2*2*3072*3072; k, v: 2*2*3072*1024; relu2 MLP: 2*2*3072*9216
-    assert costs.layer_matmul_flops(s) == \
-        37_748_736 + 12_582_912 + 113_246_208
+    assert gqa.layer_flops(conf("minitron-4b-8l")) == 8 * [
+        37_748_736 + 12_582_912 + 113_246_208]
     assert costs.head_flops(s) == 1_572_864_000
     # 32 KiB of K/V per token at 8 layers
     assert costs.decode_attn_bytes(s, 1) - costs.decode_attn_bytes(s, 0) \

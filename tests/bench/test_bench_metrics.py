@@ -24,7 +24,7 @@ def _window(records, **kw):
         bench_smoke.BENCH / "peaks.json"), t0=10.0, t1=20.0, open_loop=True,
         records=records, stats0={}, stats1={}, page_share=[], admit_lat=[],
         setup_programs=0, window_programs=0, steps=[],
-        device_kind="TPU v5 lite")
+        device_kind="TPU v5 lite", block=bench_smoke.block()[0])
     base.update(kw)
     return harness.Window(**base)
 
@@ -77,9 +77,8 @@ def test_prefill_mfu_counts_the_chunks_the_window_dispatched():
     w = _window([], steps=[(12.0, 12.1, log), (25.0, 25.1, log)],
                 trace=red, trace_t0=11.0, trace_t1=13.0)
     import costs
-    s = costs.Shape.of(w.conf)
-    flops = 16 * s.layers * costs.layer_matmul_flops(s) + \
-        costs.prefill_attn(s, 0, 16)[0]
+    s = costs.Shape.of(w.conf, w.block.layer_flops(w.conf))
+    flops = 16 * s.matmul_flops + costs.prefill_attn(s, 0, 16)[0]
     assert _read("layer_metrics", "prefill_mfu", w) == pytest.approx(
         100 * flops / (0.25 * 197e12))
     assert _read("layer_metrics", "prefill_ms_per_ktok", w) == \
@@ -89,7 +88,8 @@ def test_prefill_mfu_counts_the_chunks_the_window_dispatched():
 def test_an_unknown_device_kind_is_an_error():
     red = trace_reduce.Reduction(
         window_s=1.0, busy_s=0.5, decode_steps_s=[0.01], prefill_programs_s=0,
-        kernel_s={"decode": 0.1}, kernel_calls={}, op_self_s={}, gaps=[])
+        kernel_s={"paged_decode_attention": 0.1}, kernel_calls={},
+        op_self_s={}, gaps=[])
     w = _window([_record(11.0, t_first=11.5, tokens=[11.5, 11.6])],
                 trace=red, trace_t0=11.0, trace_t1=12.0, device_kind="TPU v9")
     with pytest.raises(ValueError, match="no peaks"):

@@ -54,8 +54,9 @@ def test_refuses_a_checkout_without_the_program(tmp_path):
 @pytest.mark.parametrize("name", ["qwen2-0.5b", "minitron-4b-8l"])
 def test_weights_are_laid_out_as_the_program_takes_them(name):
     conf = harness.load(BENCH / "configs" / f"{name}.json")
-    cfg = model.program_config(conf)
-    ours = jax.eval_shape(lambda: model.make_params(conf, 5))
+    block, _ = bench_smoke.block(name)
+    cfg = block.program_config(conf)
+    ours = jax.eval_shape(lambda: model.make_params(block.layout(conf), 5))
     theirs = jax.eval_shape(lambda: blocks.split_params(
         transformer.init_model(jax.random.PRNGKey(0), cfg))[0])
     assert jax.tree.structure(ours) == jax.tree.structure(theirs)
@@ -63,15 +64,18 @@ def test_weights_are_laid_out_as_the_program_takes_them(name):
         [(a.shape, a.dtype) for a in jax.tree.leaves(theirs)]
 
 
+def _program_config(name):
+    return bench_smoke.block(name)[0].program_config(
+        harness.load(BENCH / "configs" / f"{name}.json"))
+
+
 def test_configs_keep_every_published_width():
-    q = model.program_config(harness.load(BENCH / "configs" /
-                                          "qwen2-0.5b.json"))
+    q = _program_config("qwen2-0.5b")
     base = configs.get_config("qwen2-0.5b")
     assert (q.d_model, q.n_heads, q.n_kv, q.hd, q.d_ff, q.vocab,
             q.groups) == (base.d_model, base.n_heads, base.n_kv, base.hd,
                           base.d_ff, base.vocab, base.groups)
-    m = model.program_config(harness.load(BENCH / "configs" /
-                                          "minitron-4b-8l.json"))
+    m = _program_config("minitron-4b-8l")
     base = configs.get_config("minitron-4b")
     assert (m.d_model, m.n_heads, m.n_kv, m.hd, m.d_ff, m.vocab) == \
         (3072, 24, 8, 128, 9216, 256000) == \

@@ -5,7 +5,7 @@ device's idle time by them (``bench/serve_spans.py``).
 iterations of a profiler trace recorded on one TPU v5e in the
 ``minitron-4b-8l.batch-decode`` cell: the device's ``XLA Modules`` and
 ``XLA Ops`` events, the harness's spans and the program's ``serve.*`` spans,
-as ``serve_spans.extract`` writes them, with ``bench.window`` cut to the
+as ``trace_reduce.extract`` writes them, with ``bench.window`` cut to the
 three iterations.
 """
 import gzip
@@ -70,7 +70,7 @@ def test_an_untraced_engine_puts_its_spans_on_the_profiler(tmp_path):
     assert len(done) == 3
     # the tracer itself is off: it recorded nothing
     assert not eng.tracer.enabled and len(eng.tracer.events) == 0
-    ev = serve_spans.extract(trace_reduce.find_trace(tmp_path))
+    ev = trace_reduce.extract(trace_reduce.find_trace(tmp_path))
     names = {n for n, _, _ in ev["serve"]}
     assert names >= {"serve.iteration", "serve.submit", "serve.schedule",
                      "serve.commit", "serve.decode_inputs",
@@ -124,7 +124,7 @@ def test_idle_is_charged_to_the_innermost_span():
     # the one decode program paired ended before its fetch returned
     assert s.shift_s == [0.0]
     # labels: the serve span covering most, else the harness span
-    gaps = serve_spans.label_gaps(_events(), s)
+    gaps = serve_spans.label_gaps(_events(), s.idle)
     assert [g for g, _ in gaps] == ["serve.decode_inputs",
                                     "serve.fetch_tokens", "Engine.submit"]
     assert [t for _, t in gaps] == pytest.approx([30e-9, 20e-9, 10e-9])
@@ -168,11 +168,7 @@ def _window(trace):
         steps=[], trace=trace)
 
 
-def test_readers_read_the_split_of_the_cells_trace(tmp_path, monkeypatch):
-    (tmp_path / "cell").mkdir()
-    (tmp_path / "cell" / "t.xplane.pb").write_bytes(b"")
-    monkeypatch.setattr(serve_spans, "TRACES", tmp_path)
-    monkeypatch.setattr(serve_spans, "extract", lambda path: _events())
+def test_readers_read_the_split_of_the_cells_trace():
     w = _window(trace_reduce.reduce(_events()))
     got = {n: harness._reader("layer_metrics", n)(w) for n in READERS}
     assert got == pytest.approx({"idle_sched_ms": 12.5e-6,
@@ -181,28 +177,13 @@ def test_readers_read_the_split_of_the_cells_trace(tmp_path, monkeypatch):
                                  "idle_outside_ms": 7.5e-6})
     assert harness._reader("layer_metrics", "host_step_ms")(w) == \
         pytest.approx(22.5e-6)
-    # no device trace (the CPU), or no trace file: every reader is silent
-    for win, where in ((_window(None), tmp_path),
-                       (w, tmp_path / "elsewhere")):
-        monkeypatch.setattr(serve_spans, "TRACES", where)
+    # no device trace (the CPU), or a trace without serve spans: every
+    # reader is silent
+    ev = _events()
+    ev["serve"] = []
+    for win in (_window(None), _window(trace_reduce.reduce(ev))):
         for n in READERS + ("host_step_ms",):
             assert harness._reader("layer_metrics", n)(win) is None
-
-
-def test_readers_skip_a_trace_older_than_the_window(tmp_path, monkeypatch):
-    (tmp_path / "cell").mkdir()
-    (tmp_path / "cell" / "t.xplane.pb").write_bytes(b"")
-    monkeypatch.setattr(serve_spans, "TRACES", tmp_path)
-    monkeypatch.setattr(serve_spans, "extract", lambda path: _events())
-    w = _window(trace_reduce.reduce(_events()))
-    # a window whose trace began after the file was written: a trace left
-    # by an earlier run
-    w.trace_t0 = harness.CLOCK() + 60.0
-    for n in READERS + ("host_step_ms",):
-        assert harness._reader("layer_metrics", n)(w) is None
-    w.trace_t0 = harness.CLOCK() - 60.0
-    assert harness._reader("layer_metrics", "host_step_ms")(w) == \
-        pytest.approx(22.5e-6)
 
 
 @pytest.fixture(scope="module")
@@ -218,7 +199,7 @@ def test_the_split_of_a_chip_trace_closes(chip_events):
     assert s.window_s == pytest.approx(red.window_s)
     assert abs(sum(s.idle_s.values()) - (red.window_s - red.busy_s)) < 1e-9
     assert all(v >= 0.0 for v in s.idle_s.values())
-    gaps = serve_spans.label_gaps(chip_events, s)
+    gaps = serve_spans.label_gaps(chip_events, s.idle)
     assert gaps and all(g.startswith("serve.") or g == "outside"
                         for g, _ in gaps)
     assert s.shift_s == [0.0]
@@ -226,7 +207,8 @@ def test_the_split_of_a_chip_trace_closes(chip_events):
     # the host's own work in an iteration is shorter than the iteration
     assert 0.0 < s.host_step_ms() < 1000.0 * s.window_s / s.iterations
     # the renamed programs and kernels are still told apart
-    assert red.decode_steps_s and red.kernel_calls["decode"] > 0
+    assert red.decode_steps_s and \
+        red.kernel_calls["paged_decode_attention"] > 0
     assert {m.split("(")[0] for d in chip_events["devices"]
             for m, _, _ in d["modules"]} >= {"jit_decode_step"}
 
